@@ -28,7 +28,7 @@ def run() -> List[Row]:
     for use_kernel, tag in ((True, "pallas"), (False, "ref")):
         def go(u=use_kernel):
             binstats(ts, vals, valid, total_ns=1e9, n_bins=n_bins,
-                     use_kernel=u).block_until_ready()
+                     use_kernel=u, interpret=True).block_until_ready()
         go()
         us = timeit(go, repeat=3)
         rows.append(Row(f"kernels/binstats_{tag}", us,
@@ -40,7 +40,7 @@ def run() -> List[Row]:
     for use_kernel, tag in ((True, "pallas"), (False, "ref")):
         def go(u=use_kernel):
             jax.block_until_ready(
-                iqr_fences(scores, occ, use_kernel=u))
+                iqr_fences(scores, occ, use_kernel=u, interpret=True))
         go()
         us = timeit(go, repeat=3)
         rows.append(Row(f"kernels/iqr_{tag}", us, f"bins={m}"))
@@ -49,7 +49,8 @@ def run() -> List[Row]:
     x = jnp.asarray(rng.normal(0, 1, k), jnp.float32)
     for use_kernel, tag in ((True, "pallas"), (False, "ref")):
         def go(u=use_kernel):
-            rolling_stats(x, window=64, use_kernel=u).block_until_ready()
+            rolling_stats(x, window=64, use_kernel=u,
+                          interpret=True).block_until_ready()
         go()
         us = timeit(go, repeat=3)
         rows.append(Row(f"kernels/rolling_{tag}", us, f"n={k};w=64"))

@@ -57,6 +57,7 @@ from typing import List
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Query, run_generation, run_queries
 from repro.core.aggregation import run_aggregation
 from repro.core.anomaly import anomalous_bins
@@ -555,6 +556,7 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="also write the JSON record to this path")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fusion:
         rec = _measure_fusion(args.scale, args.smoke)
         ok = rec["fusion_speedup_ok"]

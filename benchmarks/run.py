@@ -10,6 +10,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (analyzer_scale, fig1a_stall_timeline, fig1b_variability,
                fig1c_scaling, kernels_bench, multimetric_bench, table1_join)
 
@@ -30,6 +32,7 @@ def main() -> None:
                     help="comma-separated module keys "
                          f"(default: all of {list(MODULES)})")
     args = ap.parse_args()
+    enable_compile_cache()
     keys = args.only.split(",") if args.only else list(MODULES)
 
     print("name,us_per_call,derived")

@@ -51,6 +51,7 @@ from repro.core import (GenerationConfig, PipelineConfig, SyntheticSpec,
                         VariabilityPipeline, generate_synthetic,
                         write_synthetic_dbs)
 from repro.core.anomaly import top_variability_bins
+from repro.compile_cache import enable_compile_cache
 from repro.core.events import COPY_KIND_NAMES
 
 
@@ -128,6 +129,8 @@ def main() -> None:
                          "[\"k_stall\"], \"group_by\": \"m_kind\", "
                          "\"transfer_kinds\": [1, 2]}]'")
     args = ap.parse_args()
+    if args.backend == "jax":
+        enable_compile_cache()
 
     if args.prepare_store:
         _prepare_store(args)

@@ -117,6 +117,7 @@ __all__ = [
     "bin_samples_grouped", "compute_shard_partial", "compute_partials",
     "ScanPool", "compute_lane_partials", "compute_lane_partials_jax",
     "compute_partials_jax", "classify_shards", "execute_plan",
+    "DeviceDispatch", "DEVICE_DISPATCHES",
     "rank_partial_from_shards", "load_rank_grouped", "load_rank_partials",
     "round_robin_merge", "run_aggregation", "run_incremental",
     "run_queries", "DEFAULT_METRIC", "STAT_FIELDS",
@@ -829,11 +830,42 @@ def _slotwise_device_partition(counts: Sequence[int], n_dev: int,
     return row, valid
 
 
+@dataclasses.dataclass(frozen=True)
+class DeviceDispatch:
+    """One batched device reduce of :func:`compute_lane_partials_jax`:
+    how many rows and segments it carried, and where each row input
+    landed — ``placement`` holds, per input, ``(device id, first row,
+    end row)`` for every device's section along the row axis."""
+
+    reducers: Tuple[str, ...]
+    rows: int                      # live (unpadded) rows reduced
+    n_seg: int                     # exact flat segment count
+    n_seg_dev: int                 # 128-quantized count compiled for
+    placement: Dict[str, Tuple[Tuple[int, int, int], ...]]
+
+
+# the most recent device dispatches, newest last (callers may clear it)
+DEVICE_DISPATCHES: "collections.deque[DeviceDispatch]" = collections.deque(
+    maxlen=256)
+
+
+def _row_placement(arr) -> Tuple[Tuple[int, int, int], ...]:
+    """``(device id, first row, end row)`` of each addressable shard of
+    a row-sharded array (rows are the last axis)."""
+    n = arr.shape[-1]
+    out = []
+    for sh in arr.addressable_shards:
+        start, stop, _ = sh.index[-1].indices(n)
+        out.append((int(sh.device.id), int(start), int(stop)))
+    return tuple(sorted(out, key=lambda t: t[1]))
+
+
 def compute_lane_partials_jax(store: TraceStore,
                               work_items: Sequence[Tuple[int,
                                                          Sequence[int]]],
                               lanes: Sequence[LanePlan],
                               persist: bool = True,
+                              devices: Optional[Sequence] = None,
                               ) -> Dict[int, List[ShardPartial]]:
     """The jax backend's fused dirty-shard producer: ONE batched device
     collective per reducer over every (query lane × dirty shard) slot's
@@ -863,10 +895,19 @@ def compute_lane_partials_jax(store: TraceStore,
     ``precision="float32"`` partial namespace stamped with the shard
     fingerprint — the cache a later delta serves clean shards from
     without touching a device.
+
+    ``devices`` is the mesh the collectives run on, in order; ``None``
+    means the first device alone. Each device's section of the row
+    inputs is uploaded straight to that device, never staged through
+    device 0 (every dispatch is logged in :data:`DEVICE_DISPATCHES`).
     """
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devs = list(devices) if devices is not None else jax.devices()[:1]
+    mesh = Mesh(np.asarray(devs), ("data",))
+    rows_on = NamedSharding(mesh, P("data"))
+    cols_on = NamedSharding(mesh, P(None, "data"))
 
     scans = []          # (lane idx, fingerprint, partial, raw rows)
     for idx, lane_ids in work_items:
@@ -915,17 +956,23 @@ def compute_lane_partials_jax(store: TraceStore,
                 v = np.pad(v, ((0, m_max - v.shape[0]), (0, 0)))
             vals_parts.append(v)
         vals_all = np.concatenate(vals_parts, axis=1)
-        dev = jax.devices()
         row, valid = _slotwise_device_partition(
-            [len(rows[0]) for _, _, _, rows in live], len(dev))
-        mesh = Mesh(np.asarray(dev), ("data",))
+            [len(rows[0]) for _, _, _, rows in live], len(devs))
         seg_p = seg_all[row].astype(np.int32)
         seg_p[~valid] = 0
-        # ONE host->device conversion + upload serves every reducer's
-        # collective (jnp.asarray inside device_reduce is then a no-op)
-        seg_j = jnp.asarray(seg_p)
-        vals_j = jnp.asarray(vals_all[:, row], jnp.float32)
-        valid_j = jnp.asarray(valid)
+        # ONE upload serves every reducer's collective (jnp.asarray
+        # inside device_reduce is then a no-op); each device receives
+        # only its own section, laid out as shard_map's in_specs expect
+        seg_j = jax.device_put(seg_p, rows_on)
+        vals_j = jax.device_put(vals_all[:, row].astype(np.float32),
+                                cols_on)
+        valid_j = jax.device_put(valid, rows_on)
+        DEVICE_DISPATCHES.append(DeviceDispatch(
+            reducers=tuple(suite), rows=int(valid.sum()), n_seg=n_seg,
+            n_seg_dev=n_seg_dev,
+            placement={"seg": _row_placement(seg_j),
+                       "vals": _row_placement(vals_j),
+                       "valid": _row_placement(valid_j)}))
         reduced = {name: get_reducer(name).device_reduce(
                        seg_j, vals_j, n_seg_dev, mesh, valid_j)
                    for name in suite}         # (n_seg_dev, M_max, *priv)
@@ -1372,7 +1419,8 @@ def execute_plan(qplan: QueryPlan, use_cache: bool = True,
         elif qplan.backend == "jax":
             fresh = compute_lane_partials_jax(store, work_items,
                                               qplan.lanes,
-                                              persist=use_cache)
+                                              persist=use_cache,
+                                              devices=qplan.devices)
         else:
             fresh = compute_lane_partials(store, work_items, qplan.lanes,
                                           persist=use_cache, pool=pool)
@@ -1418,14 +1466,16 @@ def execute_plan(qplan: QueryPlan, use_cache: bool = True,
 def run_queries(store: Union[str, TraceStore], queries: Sequence[Query],
                 n_ranks: Optional[int] = None, backend: str = "serial",
                 use_cache: bool = True,
-                pool: Optional[ScanPool] = None) -> List[QueryResult]:
+                pool: Optional[ScanPool] = None,
+                devices: Optional[Sequence] = None) -> List[QueryResult]:
     """Compile + execute a batch of declarative queries as one fused
     scan (``serial`` or ``jax`` backend; the process-pool backend is
     :meth:`repro.core.pipeline.VariabilityPipeline.query`). Results come
     back in query order, each with execution provenance. ``pool``
-    parallelizes the dirty-shard scan (see :class:`ScanPool`)."""
+    parallelizes the dirty-shard scan (see :class:`ScanPool`);
+    ``devices`` is the jax backend's mesh (default: the first device)."""
     qplan = QueryPlan.compile(store, list(queries), backend=backend,
-                              n_ranks=n_ranks)
+                              n_ranks=n_ranks, devices=devices)
     return qplan.execute(use_cache=use_cache, pool=pool)
 
 

@@ -52,7 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
 from .reducers import N_BUCKETS, SUBDIV, V_FLOOR
 
 STATS = 5   # count, sum, sumsq, min, max
@@ -136,9 +135,8 @@ def _collaborative_sum(vals: jnp.ndarray, axis: str, axis_size: int,
     cheaper than all-devices-all-segments `psum` for large tables: each
     link carries 1/P of the table instead of all of it.
 
-    Pads ``dim`` to a multiple of the axis size for the scatter (the size
-    is passed in statically: jax.lax.axis_size is not available on every
-    supported jax version, and the pad must be static anyway)."""
+    Pads ``dim`` to a multiple of the axis size for the scatter (the pad
+    must be static, so the size is passed in)."""
     n = vals.shape[dim]
     pad = (-n) % axis_size
     pad_width = [(0, 0)] * vals.ndim
@@ -191,8 +189,8 @@ def distributed_binstats_from_bins(bin_ids: jnp.ndarray,
         return _collaborative_reduce(local, axis, mesh.shape[axis])
 
     spec = P(axis)
-    fn = _shard_map(rank_fn, mesh,
-                    in_specs=(spec, spec, spec), out_specs=P())
+    fn = jax.shard_map(rank_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=P(), check_vma=False)
     if valid is None:
         valid = jnp.ones(values.shape, dtype=bool)
     return fn(bin_ids, values, valid)
@@ -213,9 +211,9 @@ def _moments_flat_fn(n_seg: int, mesh: Mesh, axis: str):
         return _collaborative_reduce(local, axis, mesh.shape[axis])
 
     spec = P(axis)
-    return jax.jit(_shard_map(rank_fn, mesh,
-                              in_specs=(spec, P(None, axis), spec),
-                              out_specs=P()))
+    return jax.jit(jax.shard_map(rank_fn, mesh=mesh,
+                                 in_specs=(spec, P(None, axis), spec),
+                                 out_specs=P(), check_vma=False))
 
 
 def distributed_moments_flat(seg_ids: jnp.ndarray, values: jnp.ndarray,
@@ -259,9 +257,9 @@ def _histogram_flat_fn(n_seg: int, mesh: Mesh, axis: str):
         return _collaborative_sum(local, axis, mesh.shape[axis], dim=1)
 
     spec = P(axis)
-    return jax.jit(_shard_map(rank_fn, mesh,
-                              in_specs=(spec, P(None, axis), spec),
-                              out_specs=P()))
+    return jax.jit(jax.shard_map(rank_fn, mesh=mesh,
+                                 in_specs=(spec, P(None, axis), spec),
+                                 out_specs=P(), check_vma=False))
 
 
 def distributed_histogram_flat(seg_ids: jnp.ndarray, values: jnp.ndarray,
@@ -275,8 +273,13 @@ def distributed_histogram_flat(seg_ids: jnp.ndarray, values: jnp.ndarray,
     Each metric's (segment, bucket) pair is fused into one id; the counts
     are purely additive, so they ride the SAME psum_scatter/all_gather
     round-robin path as the moments' sums. Returns replicated
-    (n_metrics, n_seg, N_BUCKETS) counts.
+    (n_metrics, n_seg, N_BUCKETS) counts. The fused id is int32, so
+    ``n_seg * N_BUCKETS`` must stay below 2**31 (about 5.59M segments).
     """
+    if n_seg * N_BUCKETS > np.iinfo(np.int32).max:
+        raise ValueError(
+            f"{n_seg:,} segments x {N_BUCKETS} buckets overflows the "
+            "int32 fused histogram id")
     if valid is None:
         valid = jnp.ones(seg_ids.shape, dtype=bool)
     out = _histogram_flat_fn(n_seg, mesh, axis)(seg_ids, values, valid)
@@ -368,8 +371,8 @@ def distributed_binstats(rel_timestamps: jnp.ndarray, values: jnp.ndarray,
         return _collaborative_reduce(local, axis, mesh.shape[axis])
 
     spec = P(axis)
-    fn = _shard_map(rank_fn, mesh,
-                    in_specs=(spec, spec, spec), out_specs=P())
+    fn = jax.shard_map(rank_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=P(), check_vma=False)
     if valid is None:
         valid = jnp.ones(values.shape, dtype=bool)
     return fn(rel_timestamps, values, valid)

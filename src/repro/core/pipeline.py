@@ -82,6 +82,11 @@ from .tracestore import StoreManifest, TraceStore
 
 # "fork" gives faithful cheap rank processes on Linux; the workers touch only
 # numpy + sqlite (jax is imported lazily, never before the fork point).
+# A process that has already reached an accelerator through jax (a jax
+# query, or the in-process service on the jax backend) holds that chip:
+# it must not run the process backend afterwards, since a forked child
+# would inherit the held device. Run such work in-process (serial / jax
+# backends) or from a parent that never touched jax.
 _MP_CONTEXT = "fork" if "fork" in mp.get_all_start_methods() else "spawn"
 
 
@@ -109,6 +114,9 @@ class PipelineConfig:
     # pack-writer thread serializes all partial-cache appends; the
     # process/jax backends bring their own parallelism and ignore it.
     scan_workers: int = 1
+    # the jax backend's mesh devices, in order (None: the first device);
+    # the other backends ignore it
+    devices: Optional[Sequence] = None
 
     @property
     def metric_list(self) -> List[str]:
@@ -344,7 +352,8 @@ class VariabilityPipeline:
         sides = []
         for sd in (store_a, store_b):
             qplan = QueryPlan.compile(sd, [dq], backend=self.cfg.backend,
-                                      n_ranks=self.cfg.n_ranks)
+                                      n_ranks=self.cfg.n_ranks,
+                                      devices=self.cfg.devices)
             res = qplan.execute(
                 use_cache=self.cfg.use_summary_cache,
                 compute_fn=(self._pool_compute
@@ -393,7 +402,7 @@ class VariabilityPipeline:
         cfg = self.cfg
         qplan = QueryPlan.compile(store_dir, list(queries),
                                   backend=cfg.backend,
-                                  n_ranks=cfg.n_ranks)
+                                  n_ranks=cfg.n_ranks, devices=cfg.devices)
         compute_fn = (self._pool_compute if cfg.backend == "process"
                       else None)
         return qplan.execute(use_cache=cfg.use_summary_cache,
@@ -456,7 +465,8 @@ class VariabilityPipeline:
         ``ingest`` is an optional
         :class:`~repro.serve.IngestConfig` for the streaming plane."""
         from repro.serve.query_service import QueryService, ServiceConfig
-        cfg = ServiceConfig(backend=self.cfg.backend, host=host,
+        cfg = ServiceConfig(backend=self.cfg.backend,
+                            devices=self.cfg.devices, host=host,
                             port=port, ingest=ingest, **cfg_kw)
         return QueryService(str(store_dir), cfg).start(
             serve_http=serve_http)
@@ -472,7 +482,8 @@ class VariabilityPipeline:
         ``GET /v1/stream/fences``. Subscribe with
         :class:`~repro.serve.QueryClient` (``client.fences(since)``)."""
         from repro.serve.query_service import QueryService, ServiceConfig
-        cfg = ServiceConfig(backend=self.cfg.backend, host=host,
+        cfg = ServiceConfig(backend=self.cfg.backend,
+                            devices=self.cfg.devices, host=host,
                             port=port, ingest=ingest, **cfg_kw)
         svc = QueryService(str(store_dir), cfg)
         svc.ensure_ingestor().attach(list(db_paths))

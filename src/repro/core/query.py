@@ -366,11 +366,14 @@ class QueryPlan:
     n_ranks: int
     backend: str
     lanes: List[LanePlan]
+    # the jax backend's mesh devices, in order (None: the first device)
+    devices: Optional[Tuple[Any, ...]] = None
 
     @classmethod
     def compile(cls, store, queries: Sequence[Query],
                 backend: str = "serial",
-                n_ranks: Optional[int] = None) -> "QueryPlan":
+                n_ranks: Optional[int] = None,
+                devices: Optional[Sequence[Any]] = None) -> "QueryPlan":
         from .tracestore import TraceStore
         if not isinstance(store, TraceStore):
             store = TraceStore(store)
@@ -412,7 +415,8 @@ class QueryPlan:
         return cls(store=store, n_shard_files=man.n_shards,
                    file_plan=file_plan,
                    n_ranks=int(n_ranks or man.n_ranks), backend=backend,
-                   lanes=lanes)
+                   lanes=lanes,
+                   devices=None if devices is None else tuple(devices))
 
     def execute(self, use_cache: bool = True, compute_fn=None,
                 pool=None) -> List[QueryResult]:

@@ -7,8 +7,13 @@
   rolling   rolling mean/std with overlapped block views
 
 Each ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper
-with use_kernel/interpret switches) and ref.py (pure-jnp oracle). Validated
-in interpret mode on CPU; compiled path targets TPU VMEM/MXU.
+with use_kernel/interpret switches; ``interpret`` is a required keyword, so
+no caller gets the interpreter without asking) and ref.py (pure-jnp
+oracle). All are validated in interpret mode on CPU only. None is on the
+trace-analysis path (``repro.core`` imports none of them), and Mosaic
+refuses ``binstats`` and ``histbin`` for TPU v5e: the ``valid[:, None]``
+broadcast is an unsupported shape cast (vector<1024xi1> ->
+vector<1024x1xi1>).
 """
 from .binstats import binstats, binstats_ref
 from .histbin import histbin, histbin_ref

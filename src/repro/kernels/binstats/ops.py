@@ -1,10 +1,11 @@
 """Jit'd public wrapper for the binstats kernel: padding + dispatch.
 
 ``binstats(...)`` pads events to the tile size and bins to the bin tile,
-then calls the Pallas kernel (interpret=True on CPU, compiled on TPU) or
-the jnp reference. ``values`` may be a single (N,) metric — returning the
-UNPADDED (n_bins, 5) moment table as before — or a batched (M, N) metric
-matrix sharing one timestamp/valid vector, returning (M, n_bins, 5). Field
+then calls the Pallas kernel (in interpret mode when the caller passes
+``interpret=True``; every caller must say) or the jnp reference.
+``values`` may be a single (N,) metric — returning the UNPADDED
+(n_bins, 5) moment table as before — or a batched (M, N) metric matrix
+sharing one timestamp/valid vector, returning (M, n_bins, 5). Field
 order matches :class:`repro.core.aggregation.BinStats`.
 """
 
@@ -26,7 +27,7 @@ from .ref import binstats_ref
                               "interpret", "ev_tile", "bin_tile"))
 def binstats(rel_ts: jnp.ndarray, values: jnp.ndarray,
              valid: jnp.ndarray, *, total_ns: float, n_bins: int,
-             use_kernel: bool = True, interpret: bool = True,
+             use_kernel: bool = True, interpret: bool,
              ev_tile: int = DEFAULT_EV_TILE,
              bin_tile: int = DEFAULT_BIN_TILE) -> jnp.ndarray:
     """Fused binning + per-bin (count, sum, sumsq, min, max) moments.

@@ -17,7 +17,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0,
                     q_tile: int = 128, kv_tile: int = 128,
                     use_kernel: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """(B, S, H, hd) model layout in/out; equal q/kv head counts
     (GQA callers expand first — see models/attention H1)."""
     b, s, h, hd = q.shape

@@ -1,9 +1,9 @@
 """Jit'd public wrapper for the histbin kernel: padding + dispatch.
 
 ``histbin(...)`` pads events to the tile size and bins to the bin tile,
-then calls the Pallas kernel (interpret=True on CPU, compiled on TPU) or
-the jnp reference. ``values`` may be a single (N,) metric — returning the
-UNPADDED (n_bins, n_buckets) count table — or a batched (M, N) metric
+then calls the Pallas kernel (in interpret mode when the caller passes
+``interpret=True``; every caller must say) or the jnp reference.
+``values`` may be a single (N,) metric — returning the UNPADDED (n_bins, n_buckets) count table — or a batched (M, N) metric
 matrix sharing one timestamp/valid vector, returning
 (M, n_bins, n_buckets). Bucket layout matches
 :class:`repro.core.reducers.QuantileSketch` (bucket axis last), so the
@@ -32,7 +32,7 @@ from .ref import histbin_ref
 def histbin(rel_ts: jnp.ndarray, values: jnp.ndarray,
             valid: jnp.ndarray, *, total_ns: float, n_bins: int,
             n_buckets: int = N_BUCKETS,
-            use_kernel: bool = True, interpret: bool = True,
+            use_kernel: bool = True, interpret: bool,
             ev_tile: int = DEFAULT_EV_TILE,
             bin_tile: int = DEFAULT_BIN_TILE) -> jnp.ndarray:
     """Fused binning + per-bin log-bucket histogram counts.

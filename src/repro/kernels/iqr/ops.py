@@ -19,7 +19,7 @@ def _next_pow2(n: int) -> int:
                    static_argnames=("k_factor", "use_kernel", "interpret"))
 def iqr_fences(scores: jnp.ndarray, occupied: jnp.ndarray, *,
                k_factor: float = 1.5, use_kernel: bool = True,
-               interpret: bool = True):
+               interpret: bool):
     """IQR anomaly detection over a per-bin score table.
 
     Returns dict with q1/q3/iqr/lo_fence/hi_fence/n_occ scalars and (n,)

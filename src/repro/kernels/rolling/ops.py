@@ -14,7 +14,7 @@ from .ref import rolling_ref
 @functools.partial(jax.jit, static_argnames=("window", "use_kernel",
                                              "interpret", "block"))
 def rolling_stats(x: jnp.ndarray, *, window: int, use_kernel: bool = True,
-                  interpret: bool = True,
+                  interpret: bool,
                   block: int = DEFAULT_BLOCK) -> jnp.ndarray:
     """Trailing-window rolling mean/std: (N,) -> (N, 2)."""
     n = x.shape[0]

@@ -22,7 +22,7 @@ from .ref import ssd_ref
 def ssd_fused(xs: jnp.ndarray, dt: jnp.ndarray, A_log: jnp.ndarray,
               B: jnp.ndarray, C: jnp.ndarray, D: jnp.ndarray, *,
               chunk: int = 128, use_kernel: bool = True,
-              interpret: bool = True):
+              interpret: bool):
     """Drop-in for models.ssm.ssd_scan: (b,s,H,P) in, (y, state) out."""
     b, s, H, P = xs.shape
     G, N = B.shape[2], B.shape[3]

@@ -29,7 +29,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 import numpy as np
 
 from .layers import dense_init
@@ -257,10 +256,11 @@ def moe_forward(params, x, cfg: MoEConfig,
                 o, a, dr = _moe_ep_body(p, tk.reshape(-1, d), cfg=cfg,
                                         tensor_axis=ax, tp=tp)
                 return finalize(o, tk, a, dr)
-            fn = shard_map(
+            fn = jax.shard_map(
                 ep, mesh=mesh,
                 in_specs=(pspec, P(ctx.batch, ax, None)),
-                out_specs=(P(ctx.batch, ax, None), P(), P()))
+                out_specs=(P(ctx.batch, ax, None), P(), P()),
+                check_vma=False)
             out, aux, dropped = fn(in_params, x)
         elif cfg.n_experts % tp == 0 and getattr(ctx, "inference", False):
             # §Perf H8: weights-stationary decode — tokens fully
@@ -281,10 +281,11 @@ def moe_forward(params, x, cfg: MoEConfig,
                     p, tk.reshape(-1, d), cfg=cfg, all_axes=all_axes,
                     tensor_axis=ax, tp=tp)
                 return o.reshape(tk.shape), a, dr
-            fn = shard_map(
+            fn = jax.shard_map(
                 sta, mesh=mesh,
                 in_specs=(pspec_inf, P(None, None, None)),
-                out_specs=(P(None, None, None), P(), P()))
+                out_specs=(P(None, None, None), P(), P()),
+                check_vma=False)
             out, aux, dropped = fn(in_params, x)
         elif cfg.n_experts % tp == 0:
             # replicated dispatch (decode)
@@ -293,10 +294,11 @@ def moe_forward(params, x, cfg: MoEConfig,
                                                 cfg=cfg, tensor_axis=ax,
                                                 tp=tp)
                 return finalize(o, tk, a, dr)
-            fn = shard_map(
+            fn = jax.shard_map(
                 rep, mesh=mesh,
                 in_specs=(pspec, P(ctx.batch, None, None)),
-                out_specs=(P(ctx.batch, None, None), P(), P()))
+                out_specs=(P(ctx.batch, None, None), P(), P()),
+                check_vma=False)
             out, aux, dropped = fn(in_params, x)
         else:                       # experts not divisible by the TP axis
             out, aux, dropped = _moe_local(params, tokens, cfg)

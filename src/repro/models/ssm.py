@@ -259,7 +259,8 @@ def ssm_forward(params, x, cfg: SSMConfig, ctx=None,
     if cfg.use_pallas:
         from repro.kernels.ssd import ssd_fused
         y, h_fin = ssd_fused(xs, dt, params["A_log"], B3, C3,
-                             params["D"], chunk=cfg.chunk)
+                             params["D"], chunk=cfg.chunk,
+                             interpret=jax.default_backend() != "tpu")
     else:
         y, h_fin = ssd_scan(xs, dt, params["A_log"], B3, C3,
                             params["D"], cfg.chunk)

@@ -28,7 +28,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .attention import AttnConfig, chunked_attention
@@ -70,8 +69,8 @@ def ffn_tp(params: Dict, x: jnp.ndarray, activation: str,
     if gated:
         pspec["w_gate"] = P(None, ax)
     bs = _bspec(ctx, x.shape[0], 3)
-    fn = shard_map(body, mesh=ctx.mesh,
-                   in_specs=(pspec, bs), out_specs=bs)
+    fn = jax.shard_map(body, mesh=ctx.mesh, in_specs=(pspec, bs),
+                       out_specs=bs, check_vma=False)
     return fn({k: params[k] for k in pspec}, x)
 
 
@@ -131,9 +130,9 @@ def attn_tp(params: Dict, x: jnp.ndarray, cfg: AttnConfig, positions,
         in_p.update({k: params[k] for k in ("bq", "bk", "bv")})
     bs3 = _bspec(ctx, x.shape[0], 3)
     bs4 = _bspec(ctx, x.shape[0], 4)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(pspec, bs3, P()),
-        out_specs=(bs3, bs4, bs4))
+        out_specs=(bs3, bs4, bs4), check_vma=False)
     y, k, v = fn(in_p, x, positions)
     return y, ({"k": k, "v": v} if mode == "prefill" else None)

@@ -178,6 +178,8 @@ class _Server(ThreadingHTTPServer):
 class ServiceConfig:
     tick_ms: float = 10.0                # admission-batch window
     backend: str = "serial"
+    # the jax backend's mesh devices, in order (None: the first device)
+    devices: Optional[Sequence] = None
     max_cells_per_request: int = 50_000_000
     summary_budget_bytes: Optional[int] = 256 * 1024 * 1024
     pack_budget_bytes: Optional[int] = None   # None/0 = unbounded
@@ -649,7 +651,8 @@ class QueryService:
             if tick.owned and tick.ingest_error is None:
                 qplan = QueryPlan.compile(self.store,
                                           [q for q, _ in tick.owned],
-                                          backend=self.cfg.backend)
+                                          backend=self.cfg.backend,
+                                          devices=self.cfg.devices)
                 # pin this tick's summary keys and pack shard set
                 # against eviction BEFORE any probe or scan starts
                 self.cache.register(
@@ -1213,6 +1216,9 @@ def main() -> None:
     ap.add_argument("--poll-ms", type=float, default=25.0,
                     help="ingest tailer watermark-probe cadence")
     args = ap.parse_args()
+    if args.backend == "jax":
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
     cfg = ServiceConfig(
         tick_ms=args.tick_ms, backend=args.backend,
         max_cells_per_request=args.max_cells,

@@ -7,11 +7,6 @@ import subprocess
 import sys
 import textwrap
 
-# The jax>=0.6 API drift (AxisType / set_mesh / make_mesh kwargs) that
-# used to quarantine this whole module is absorbed by repro.compat
-# (make_mesh / set_mesh / shard_map); the snippets below run on every
-# supported jax and a regression in the distributed path fails loudly.
-
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -20,7 +15,13 @@ def _run(code: str, timeout=560):
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n"
             "import sys\n"
-            f"sys.path.insert(0, {SRC!r})\n" + textwrap.dedent(code))
+            f"sys.path.insert(0, {SRC!r})\n"
+            "import jax\n"
+            "from jax.sharding import AxisType\n"
+            "def make_mesh(shape, names):\n"
+            "    return jax.make_mesh(shape, names,\n"
+            "        axis_types=(AxisType.Auto,) * len(shape))\n"
+            + textwrap.dedent(code))
     out = subprocess.run([sys.executable, "-c", full],
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0 and "OK" in out.stdout, \
@@ -30,7 +31,6 @@ def _run(code: str, timeout=560):
 def test_distributed_binstats_equals_serial():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import make_mesh, set_mesh
     from jax.sharding import Mesh
     from repro.core.distributed import (binstats_local,
                                         distributed_binstats)
@@ -55,7 +55,6 @@ def test_distributed_binstats_equals_serial():
 def test_moe_ep_and_replicated_equal_local():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import make_mesh, set_mesh
     from repro.models.moe import MoEConfig, moe_init, moe_forward
     from repro.models.shardrules import make_ctx
     cfg = MoEConfig(d_model=32, d_ff=16, n_experts=8, top_k=2,
@@ -66,7 +65,7 @@ def test_moe_ep_and_replicated_equal_local():
     out_l, _ = moe_forward(params, x, cfg, None)
     mesh = make_mesh((2, 4), ('data', 'model'))
     ctx = make_ctx(mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_ep, _ = moe_forward(params, x, cfg, ctx)
         out_rep, _ = moe_forward(params, x[:, :1], cfg, ctx)
     out_lr, _ = moe_forward(params, x[:, :1], cfg, None)
@@ -81,7 +80,6 @@ def test_moe_ep_and_replicated_equal_local():
 def test_sharded_train_step_matches_single_device():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import make_mesh, set_mesh
     from repro.configs import get_smoke_config
     from repro.data.pipeline import DataConfig, make_batch
     from repro.train.step import (TrainConfig, init_state,
@@ -101,7 +99,7 @@ def test_sharded_train_step_matches_single_device():
     bspec = to_named(batch_specs(batch, mesh), mesh)
     step = jax.jit(make_train_step(cfg, tcfg, mesh),
                    in_shardings=(sspec, bspec), out_shardings=(sspec, None))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_sh, m_sh = step(state, batch)
     np.testing.assert_allclose(float(m_ref['loss']), float(m_sh['loss']),
                                rtol=2e-3)
@@ -115,7 +113,6 @@ def test_sharded_train_step_matches_single_device():
 def test_serve_cache_specs_are_legal_shardings():
     _run("""
     import jax, jax.numpy as jnp
-    from repro.compat import make_mesh
     from repro.configs import get_smoke_config
     from repro.models.model import init_cache
     from repro.serve.engine import cache_specs
@@ -133,7 +130,6 @@ def test_serve_cache_specs_are_legal_shardings():
 
 def test_multipod_mesh_axes():
     _run("""
-    from repro.compat import make_mesh
     from repro.models.shardrules import batch_axes, spec_for
     mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
     assert batch_axes(mesh) == ('pod', 'data')
@@ -152,7 +148,6 @@ def test_elastic_checkpoint_reshard_across_meshes(tmp_path):
     step keeps producing the same loss."""
     _run("""
     import tempfile, jax, jax.numpy as jnp, numpy as np
-    from repro.compat import make_mesh, set_mesh
     from repro.configs import get_smoke_config
     from repro.data.pipeline import DataConfig, make_batch
     from repro.models.shardrules import tree_shardings
@@ -179,7 +174,7 @@ def test_elastic_checkpoint_reshard_across_meshes(tmp_path):
                     in_shardings=(sspec8, to_named(
                         batch_specs(batch, mesh8), mesh8)),
                     out_shardings=(sspec8, None))
-    with set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         state, _ = step8(state, batch)
         state, m8 = step8(state, batch)
     mgr = CheckpointManager(d)
@@ -200,10 +195,10 @@ def test_elastic_checkpoint_reshard_across_meshes(tmp_path):
                     in_shardings=(sspec4, to_named(
                         batch_specs(batch, mesh4), mesh4)),
                     out_shardings=(sspec4, None))
-    with set_mesh(mesh4):
+    with jax.set_mesh(mesh4):
         _, m4 = step4(restored, batch)
     # the 3rd-step loss on the downscaled mesh matches the 8-device run
-    with set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         _, m8b = step8(state, batch)
     np.testing.assert_allclose(float(m4['loss']), float(m8b['loss']),
                                rtol=2e-3)

@@ -196,11 +196,13 @@ def test_jax_delta_bit_identical_on_multi_device_mesh(tmp_path):
     os.environ.setdefault('JAX_PLATFORMS', 'cpu')
     import sys
     sys.path.insert(0, {src!r})
+    import jax
     import numpy as np
-    from repro.core import (SyntheticSpec, TraceStore, append_rank_db,
-                            generate_synthetic, run_aggregation,
-                            run_append, run_generation, trace_remainder,
-                            truncate_trace, write_rank_db)
+    from repro.core import (Query, SyntheticSpec, TraceStore,
+                            append_rank_db, generate_synthetic,
+                            run_append, run_generation, run_queries,
+                            trace_remainder, truncate_trace, write_rank_db)
+    from repro.core.aggregation import DEVICE_DISPATCHES
     NS = 1_000_000_000
     spec = SyntheticSpec(n_ranks=2, kernels_per_rank=2000,
                          memcpys_per_rank=300, duration_s=20.0, seed=5)
@@ -213,17 +215,28 @@ def test_jax_delta_bit_identical_on_multi_device_mesh(tmp_path):
         write_rank_db(p, truncate_trace(tr, cutoff))
     out = os.path.join(d, 'store')
     run_generation(paths, out, n_ranks=2)
-    kw = dict(metrics={METRICS!r}, group_by='m_kind',
-              reducers=('moments', 'quantile'), backend='jax')
-    run_aggregation(TraceStore(out), **kw)
+    q = Query(metrics=tuple({METRICS!r}), group_by='m_kind',
+              reducers=('moments', 'quantile'))
+    devs = jax.devices()
+    assert len(devs) == 8
+
+    def agg(store):
+        return run_queries(store, [q], backend='jax',
+                           devices=devs)[0].result
+    agg(TraceStore(out))
     for tr, p in zip(ds.traces, paths):
         append_rank_db(p, trace_remainder(tr, cutoff))
     run_append(paths, out)
-    delta = run_aggregation(TraceStore(out), **kw)
+    delta = agg(TraceStore(out))
     cs = TraceStore(out)
     cs.clear_summaries(); cs.clear_partials()
-    cold = run_aggregation(cs, **kw)
+    cold = agg(cs)
     assert len(delta.recomputed_shards) < len(cold.recomputed_shards)
+    # the mesh held all 8 devices, each with its own section of the rows
+    ids = sorted(d.id for d in devs)
+    for disp in DEVICE_DISPATCHES:
+        for sections in disp.placement.values():
+            assert sorted(dev for dev, _, _ in sections) == ids
     for f in ('count', 'sum', 'sumsq', 'min', 'max'):
         np.testing.assert_array_equal(getattr(delta.grouped, f),
                                       getattr(cold.grouped, f))

@@ -34,9 +34,9 @@ def test_binstats_kernel_matches_ref(n, n_bins):
     ts, vals = _events(rng, n, total)
     valid = jnp.asarray(rng.random(n) > 0.1)
     out_k = binstats(ts, vals, valid, total_ns=total, n_bins=n_bins,
-                     use_kernel=True)
+                     use_kernel=True, interpret=True)
     out_r = binstats(ts, vals, valid, total_ns=total, n_bins=n_bins,
-                     use_kernel=False)
+                     use_kernel=False, interpret=True)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-5, atol=1e-2)
 
@@ -47,9 +47,10 @@ def test_binstats_tile_shapes(ev_tile, bin_tile):
     ts, vals = _events(rng, 2000, 1e9)
     valid = jnp.ones(2000, bool)
     out_k = binstats(ts, vals, valid, total_ns=1e9, n_bins=100,
-                     use_kernel=True, ev_tile=ev_tile, bin_tile=bin_tile)
+                     use_kernel=True, ev_tile=ev_tile, bin_tile=bin_tile,
+                     interpret=True)
     out_r = binstats(ts, vals, valid, total_ns=1e9, n_bins=100,
-                     use_kernel=False)
+                     use_kernel=False, interpret=True)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-5, atol=1e-2)
 
@@ -61,7 +62,7 @@ def test_binstats_matches_host_aggregation():
     ts, vals = _events(rng, n, total)
     valid = jnp.ones(n, bool)
     out = np.asarray(binstats(ts, vals, valid, total_ns=total,
-                              n_bins=n_bins, use_kernel=True))
+                              n_bins=n_bins, use_kernel=True, interpret=True))
     plan = ShardPlan(0, int(total), n_bins)
     # identical float32 binning contract
     bins = np.clip((np.asarray(ts) * np.float32(n_bins / total)
@@ -81,9 +82,9 @@ if HAVE_HYPOTHESIS:
         ts, vals = _events(rng, n, 1e8)
         valid = jnp.asarray(rng.random(n) > 0.2)
         k = binstats(ts, vals, valid, total_ns=1e8, n_bins=n_bins,
-                     use_kernel=True)
+                     use_kernel=True, interpret=True)
         r = binstats(ts, vals, valid, total_ns=1e8, n_bins=n_bins,
-                     use_kernel=False)
+                     use_kernel=False, interpret=True)
         np.testing.assert_allclose(np.asarray(k), np.asarray(r),
                                    rtol=1e-5, atol=1e-2)
 else:
@@ -103,15 +104,15 @@ def test_binstats_multimetric_matches_single_runs():
     valid = jnp.asarray(rng.random(n) > 0.1)
     batch = jnp.stack([v0, v1, v2])
     mk = binstats(ts, batch, valid, total_ns=total, n_bins=n_bins,
-                  use_kernel=True)
+                  use_kernel=True, interpret=True)
     mr = binstats(ts, batch, valid, total_ns=total, n_bins=n_bins,
-                  use_kernel=False)
+                  use_kernel=False, interpret=True)
     assert mk.shape == (3, n_bins, 5)
     np.testing.assert_allclose(np.asarray(mk), np.asarray(mr),
                                rtol=1e-5, atol=1e-2)
     for j, v in enumerate((v0, v1, v2)):
         single = binstats(ts, v, valid, total_ns=total, n_bins=n_bins,
-                          use_kernel=True)
+                          use_kernel=True, interpret=True)
         np.testing.assert_allclose(np.asarray(mk[j]), np.asarray(single),
                                    rtol=1e-5, atol=1e-2)
         # counts are metric-independent and exactly shared
@@ -133,9 +134,9 @@ def test_histbin_kernel_matches_ref(n, n_bins):
     vals = jnp.asarray(np.abs(rng.normal(5000, 3000, n)), jnp.float32)
     valid = jnp.asarray(rng.random(n) > 0.1)
     out_k = histbin(ts, vals, valid, total_ns=total, n_bins=n_bins,
-                    use_kernel=True)
+                    use_kernel=True, interpret=True)
     out_r = histbin(ts, vals, valid, total_ns=total, n_bins=n_bins,
-                    use_kernel=False)
+                    use_kernel=False, interpret=True)
     assert out_k.shape == (n_bins, N_BUCKETS)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
     assert float(np.asarray(out_k).sum()) == float(np.asarray(valid).sum())
@@ -152,11 +153,11 @@ def test_histbin_multimetric_matches_single_runs():
     valid = jnp.asarray(rng.random(n) > 0.2)
     batch = jnp.stack([v0, v1])
     mk = histbin(ts, batch, valid, total_ns=total, n_bins=n_bins,
-                 use_kernel=True)
+                 use_kernel=True, interpret=True)
     assert mk.shape == (2, n_bins, N_BUCKETS)
     for j, v in enumerate((v0, v1)):
         single = histbin(ts, v, valid, total_ns=total, n_bins=n_bins,
-                         use_kernel=True)
+                         use_kernel=True, interpret=True)
         np.testing.assert_array_equal(np.asarray(mk[j]),
                                       np.asarray(single))
 
@@ -171,7 +172,7 @@ def test_histbin_feeds_quantile_sketch():
     valid = np.ones(n, bool)
     out = np.asarray(histbin(jnp.asarray(ts), jnp.asarray(vals),
                              jnp.asarray(valid), total_ns=total,
-                             n_bins=n_bins, use_kernel=True))
+                             n_bins=n_bins, use_kernel=True, interpret=True))
     sk = QuantileSketch(counts=out.astype(np.float64))
     # host sketch over identical float32-binned rows
     host = np.zeros((n_bins, N_BUCKETS))
@@ -193,8 +194,10 @@ def test_iqr_kernel_matches_ref(n):
     s = rng.normal(10, 2, n).astype(np.float32)
     s[rng.integers(0, n, 3)] *= 10
     occ = s != 0
-    k = iqr_fences(jnp.asarray(s), jnp.asarray(occ), use_kernel=True)
-    r = iqr_fences(jnp.asarray(s), jnp.asarray(occ), use_kernel=False)
+    k = iqr_fences(jnp.asarray(s), jnp.asarray(occ), use_kernel=True,
+                   interpret=True)
+    r = iqr_fences(jnp.asarray(s), jnp.asarray(occ), use_kernel=False,
+                   interpret=True)
     for key in ("q1", "q3", "hi_fence"):
         np.testing.assert_allclose(float(k[key]), float(r[key]),
                                    rtol=1e-4, atol=1e-4)
@@ -206,7 +209,7 @@ def test_iqr_kernel_sorted_output_is_sorted():
     rng = np.random.default_rng(0)
     s = rng.normal(0, 5, 200).astype(np.float32)
     k = iqr_fences(jnp.asarray(s), jnp.asarray(np.ones(200, bool)),
-                   use_kernel=True)
+                   use_kernel=True, interpret=True)
     srt = np.asarray(k["sorted"])
     assert np.all(np.diff(srt) >= 0)
 
@@ -214,7 +217,8 @@ def test_iqr_kernel_sorted_output_is_sorted():
 def test_iqr_matches_numpy_quartiles():
     rng = np.random.default_rng(5)
     s = np.abs(rng.normal(10, 3, 501)).astype(np.float32)
-    k = iqr_fences(jnp.asarray(s), jnp.asarray(s != 0), use_kernel=True)
+    k = iqr_fences(jnp.asarray(s), jnp.asarray(s != 0), use_kernel=True,
+                   interpret=True)
     q1, q3 = np.percentile(s, [25, 75])
     np.testing.assert_allclose(float(k["q1"]), q1, rtol=2e-2)
     np.testing.assert_allclose(float(k["q3"]), q3, rtol=2e-2)
@@ -227,8 +231,10 @@ def test_iqr_matches_numpy_quartiles():
 def test_rolling_kernel_matches_ref(n, window):
     rng = np.random.default_rng(n + window)
     x = rng.normal(0, 2, n).astype(np.float32)
-    k = rolling_stats(jnp.asarray(x), window=window, use_kernel=True)
-    r = rolling_stats(jnp.asarray(x), window=window, use_kernel=False)
+    k = rolling_stats(jnp.asarray(x), window=window, use_kernel=True,
+                      interpret=True)
+    r = rolling_stats(jnp.asarray(x), window=window, use_kernel=False,
+                      interpret=True)
     np.testing.assert_allclose(np.asarray(k), np.asarray(r),
                                rtol=1e-4, atol=1e-4)
 
@@ -238,7 +244,7 @@ def test_rolling_matches_numpy():
     n, w = 300, 16
     x = rng.normal(5, 3, n).astype(np.float32)
     out = np.asarray(rolling_stats(jnp.asarray(x), window=w,
-                                   use_kernel=True))
+                                   use_kernel=True, interpret=True))
     for i in (w - 1, n // 2, n - 1):
         seg = x[max(0, i - w + 1): i + 1]
         np.testing.assert_allclose(out[i, 0], seg.mean(), rtol=1e-4,
@@ -265,9 +271,9 @@ def test_ssd_kernel_matches_oracle_and_scan(b, s, H, P, G, N, chunk):
     C = jnp.asarray(rng.normal(size=(b, s, G, N)), jnp.float32)
     D = jnp.asarray(rng.normal(size=(H,)), jnp.float32)
     yk, hk = ssd_fused(xs, dt, A_log, B, C, D, chunk=chunk,
-                       use_kernel=True)
+                       use_kernel=True, interpret=True)
     yr, hr = ssd_fused(xs, dt, A_log, B, C, D, chunk=chunk,
-                       use_kernel=False)
+                       use_kernel=False, interpret=True)
     y0, h0 = ssd_scan(xs, dt, A_log, B, C, D, chunk=chunk)
     np.testing.assert_allclose(np.asarray(yk), np.asarray(yr),
                                rtol=1e-4, atol=1e-4)
@@ -287,8 +293,10 @@ def test_ssd_kernel_bf16_inputs():
     B = jnp.asarray(rng.normal(size=(b, s, G, N)), jnp.bfloat16)
     C = jnp.asarray(rng.normal(size=(b, s, G, N)), jnp.bfloat16)
     D = jnp.ones((H,), jnp.float32)
-    yk, hk = ssd_fused(xs, dt, A_log, B, C, D, chunk=16, use_kernel=True)
-    yr, hr = ssd_fused(xs, dt, A_log, B, C, D, chunk=16, use_kernel=False)
+    yk, hk = ssd_fused(xs, dt, A_log, B, C, D, chunk=16, use_kernel=True,
+                       interpret=True)
+    yr, hr = ssd_fused(xs, dt, A_log, B, C, D, chunk=16, use_kernel=False,
+                       interpret=True)
     assert yk.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(yk, np.float32),
                                np.asarray(yr, np.float32),
@@ -330,9 +338,10 @@ def test_flash_attention_kernel_matches_refs(s, causal, window, dtype):
     k = jnp.asarray(rng.normal(size=(b, s, h, hd)), dtype)
     v = jnp.asarray(rng.normal(size=(b, s, h, hd)), dtype)
     ok = flash_attention(q, k, v, causal=causal, window=window,
-                         q_tile=32, kv_tile=32, use_kernel=True)
+                         q_tile=32, kv_tile=32, use_kernel=True,
+                         interpret=True)
     orf = flash_attention(q, k, v, causal=causal, window=window,
-                          use_kernel=False)
+                          use_kernel=False, interpret=True)
     tol = 2e-4 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(ok, np.float32),
                                np.asarray(orf, np.float32),
@@ -350,7 +359,7 @@ def test_flash_attention_tile_invariance():
     q = jnp.asarray(rng.normal(size=(1, 64, 2, 8)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 64, 2, 8)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(1, 64, 2, 8)), jnp.float32)
-    a = flash_attention(q, k, v, q_tile=16, kv_tile=16)
-    b = flash_attention(q, k, v, q_tile=64, kv_tile=32)
+    a = flash_attention(q, k, v, q_tile=16, kv_tile=16, interpret=True)
+    b = flash_attention(q, k, v, q_tile=64, kv_tile=32, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-4)

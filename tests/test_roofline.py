@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis_dict
 from repro.roofline import (PEAK_FLOPS, Roofline, active_param_count,
                             model_flops_for, parse_collectives)
 from repro.roofline.hlo_cost import analyze_hlo
@@ -16,10 +15,7 @@ def _compile(f, *args):
 
 
 def test_cost_analysis_undercounts_scans_and_walker_fixes_it():
-    """Documents the XLA behaviour the walker exists for.
-
-    ``cost_analysis()`` returns a list on jax<0.5 and a dict after;
-    ``repro.compat.cost_analysis_dict`` absorbs the drift."""
+    """Documents the XLA behaviour the walker exists for."""
     w = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     x = jax.ShapeDtypeStruct((8, 256), jnp.float32)
 
@@ -30,7 +26,7 @@ def test_cost_analysis_undercounts_scans_and_walker_fixes_it():
         return y
     c = _compile(f, x, w)
     expected = 2 * 8 * 256 * 256 * 12
-    ca = cost_analysis_dict(c).get("flops", 0)
+    ca = c.cost_analysis().get("flops", 0)
     assert ca < expected / 2                  # the gap
     walked = analyze_hlo(c.as_text(), 1)
     np.testing.assert_allclose(walked.flops, expected, rtol=1e-6)
@@ -75,9 +71,10 @@ def test_collective_parse_and_wire_factors(tmp_path):
         import jax, jax.numpy as jnp, sys
         sys.path.insert(0, %r)
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.roofline import parse_collectives
-        mesh = make_mesh((2,4), ('data','model'))
+        mesh = jax.make_mesh((2,4), ('data','model'),
+                             axis_types=(AxisType.Auto,) * 2)
         x = jax.ShapeDtypeStruct((64, 512), jnp.float32)
         w1 = jax.ShapeDtypeStruct((512, 1024), jnp.float32)
         w2 = jax.ShapeDtypeStruct((1024, 512), jnp.float32)

@@ -131,9 +131,9 @@ def test_async_checkpoint_and_resume(tmp_path):
     mgr.wait()
     assert mgr.latest_step() == 5
     # elastic restore path: placement with explicit shardings (1-device)
-    from repro.compat import make_mesh
     from repro.models.shardrules import tree_shardings
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = {"step": jax.sharding.NamedSharding(
               mesh, jax.sharding.PartitionSpec()),
           "params": tree_shardings(state["params"], mesh),
