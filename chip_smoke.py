@@ -288,9 +288,11 @@ def compare_rendered(tag: str, got: Dict, want: Dict,
                 _eq_check(where, f, np.float32(g[f]), np.float32(w[f]))
 
 
-def check_placement(devices: Sequence) -> None:
+def check_placement(devices: Sequence, queries: Sequence[Query]) -> None:
     """Every device dispatch held each row input as one section per mesh
-    device, in mesh order, each device holding only its own section."""
+    device, in mesh order, each device holding only its own section.
+    Logs each dispatch's segment count with the queries fused into it
+    (one dispatch per reducer suite)."""
     ids = [int(d.id) for d in devices]
     if not DEVICE_DISPATCHES:
         raise AssertionError("no device dispatch ran")
@@ -303,8 +305,10 @@ def check_placement(devices: Sequence) -> None:
                 raise AssertionError(
                     f"input {name!r} placed as {sections}, expected one "
                     f"section per device {want}")
+        fused = [i for i, q in enumerate(queries)
+                 if q.canonical_reducers == disp.reducers]
         log(f"dispatch: reducers={','.join(disp.reducers)} "
-            f"rows={disp.rows} n_seg={disp.n_seg} "
+            f"queries={fused} rows={disp.rows} n_seg={disp.n_seg} "
             f"n_seg_dev={disp.n_seg_dev} devices={ids} "
             f"placement=own-section-per-device")
 
@@ -340,7 +344,7 @@ def device_answers(store_dir: str, queries: Sequence[Query],
                                  f"cold: {qr.provenance()}")
         log(f"{tag}: {q.to_spec()} rows_scanned={qr.rows_scanned} "
             f"{qr.provenance()}")
-    check_placement(devices)
+    check_placement(devices, queries)
     return out
 
 
@@ -393,7 +397,7 @@ def serve_and_compare(store_dir: str, queries: Sequence[Query],
                                      ref[i].result.grouped.count.max())))
                 log(f"http {attempt} query {i}: {got['provenance']}")
             if attempt == "cold":
-                check_placement(devices)
+                check_placement(devices, queries)
         log("http answers match the serial host path; the repeat was a "
             "summary-cache hit")
     finally:
@@ -441,9 +445,10 @@ def main(argv=None) -> int:
               f"devices, JAX shows {len(all_devs)}", file=sys.stderr)
         return 2
     devices = all_devs[:args.chips]
+    coords = [(int(d.id), tuple(getattr(d, "coords", ()))) for d in all_devs]
     log(f"device: platform={first.platform} kind={first.device_kind} "
         f"count={len(all_devs)} mesh={[int(d.id) for d in devices]} "
-        f"all={[(int(d.id), tuple(getattr(d, 'coords', ()))) for d in all_devs]}")
+        f"all={coords}")
 
     from repro.compile_cache import enable_compile_cache
     log(f"compile cache: {enable_compile_cache()}")

@@ -3,8 +3,9 @@
 ``histbin(...)`` pads events to the tile size and bins to the bin tile,
 then calls the Pallas kernel (in interpret mode when the caller passes
 ``interpret=True``; every caller must say) or the jnp reference.
-``values`` may be a single (N,) metric — returning the UNPADDED (n_bins, n_buckets) count table — or a batched (M, N) metric
-matrix sharing one timestamp/valid vector, returning
+``values`` may be a single (N,) metric — returning the UNPADDED
+(n_bins, n_buckets) count table — or a batched (M, N) metric matrix
+sharing one timestamp/valid vector, returning
 (M, n_bins, n_buckets). Bucket layout matches
 :class:`repro.core.reducers.QuantileSketch` (bucket axis last), so the
 output drops straight into ``QuantileSketch(counts=...)``.
