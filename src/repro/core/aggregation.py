@@ -109,6 +109,7 @@ from .query import (DEFAULT_METRIC, LanePlan, Query, QueryPlan,
 from .reducers import (BinStats, QuantileSketch, get_reducer,
                        normalize_reducers)
 from .sharding import ShardPlan, assignment, cyclic_assignment
+from .spans import span
 from .tracestore import SUMMARY_VERSION, TraceStore
 
 __all__ = [
@@ -348,67 +349,69 @@ def _scan_shard(store: TraceStore, idx: int, plan: ShardPlan,
     across lanes. Returns ``(partial-with-empty-states, rows)`` where
     ``rows`` is ``None`` for an empty shard, else
     ``(ts, vals (M, N), local_bin, gids)`` for the producer to reduce."""
-    if cols is None:
-        cols = store.read_shard(int(idx))
-    missing = [m for m in metrics if m not in cols]
-    if missing:
-        raise KeyError(f"metrics {missing} not in shard columns "
-                       f"{sorted(cols)}")
-    if group_by is not None and group_by not in cols:
-        raise KeyError(f"group_by column {group_by!r} not in shard "
-                       f"columns {sorted(cols)}")
-    rows_seen = int(np.asarray(cols["k_start"]).shape[0])
-    rows_kept = rows_seen
-    if query is not None:
-        mask = query.row_mask(cols)
-        if mask is not None:
-            # materialize only the columns the rest of the scan touches,
-            # through an index vector rather than the boolean mask —
-            # boolean fancy-indexing rescans all n rows PER COLUMN,
-            # where flatnonzero pays O(n) once and O(kept) per column;
-            # at fused-batch rates (every lane × every shard) that
-            # difference is a measurable slice of the pass
-            sel = np.flatnonzero(mask)
-            needed = {"k_start", "joined", "m_bytes", "m_kind", "m_start",
-                      *metrics}
-            if group_by is not None:
-                needed.add(group_by)
-            cols = {c: np.asarray(v)[sel] for c, v in cols.items()
-                    if c in needed}
-            rows_kept = int(sel.size)
-    ts = cols["k_start"].astype(np.int64)
-    if ts.size == 0:
-        # an empty (or fully filtered) shard contributes no rows and NO
-        # group keys
-        return ShardPartial(
-            idx=int(idx), n_bins=plan.n_shards,
-            bins=np.zeros(0, np.int64), group_keys=np.zeros(0, np.float64),
-            states={}, kind_keys=np.zeros(0, np.int64),
-            kind_bytes=np.zeros((0, plan.n_shards)),
-            rows_seen=rows_seen, rows_kept=rows_kept), None
-    vals = np.stack([np.asarray(cols[m], np.float64) for m in metrics],
-                    axis=0)
-    if group_by is None:
-        keys = np.asarray([_NO_GROUP_KEY])
-        gids = np.zeros(len(ts), np.int64)
-    else:
-        keys, gids = np.unique(np.asarray(cols[group_by], np.float64),
-                               return_inverse=True)
-    bins, local_bin = _bounded_unique(plan.shard_of(ts), plan.n_shards)
-    kind_bytes: Dict[int, np.ndarray] = {}
-    _shard_kind_bytes(cols, plan, kind_bytes)
-    kinds = sorted(kind_bytes)
-    joined = cols["joined"] > 0 if "joined" in cols else np.zeros(0, bool)
-    m_start_hi = (int(cols["m_start"][joined].max())
-                  if joined.any() else -1)
-    sp = ShardPartial(
-        idx=int(idx), n_bins=plan.n_shards, bins=bins,
-        group_keys=np.asarray(keys, np.float64), states={},
-        kind_keys=np.asarray(kinds, np.int64),
-        kind_bytes=(np.stack([kind_bytes[k] for k in kinds]) if kinds
-                    else np.zeros((0, plan.n_shards))),
-        m_start_hi=m_start_hi, rows_seen=rows_seen, rows_kept=rows_kept)
-    return sp, (ts, vals, local_bin, gids)
+    with span("repro.scan.prep") as prep:
+        if cols is None:
+            cols = store.read_shard(int(idx))
+        missing = [m for m in metrics if m not in cols]
+        if missing:
+            raise KeyError(f"metrics {missing} not in shard columns "
+                           f"{sorted(cols)}")
+        if group_by is not None and group_by not in cols:
+            raise KeyError(f"group_by column {group_by!r} not in shard "
+                           f"columns {sorted(cols)}")
+        rows_seen = int(np.asarray(cols["k_start"]).shape[0])
+        prep.set(rows=rows_seen)
+        rows_kept = rows_seen
+        if query is not None:
+            mask = query.row_mask(cols)
+            if mask is not None:
+                # materialize only the columns the rest of the scan touches,
+                # through an index vector rather than the boolean mask —
+                # boolean fancy-indexing rescans all n rows PER COLUMN,
+                # where flatnonzero pays O(n) once and O(kept) per column;
+                # at fused-batch rates (every lane × every shard) that
+                # difference is a measurable slice of the pass
+                sel = np.flatnonzero(mask)
+                needed = {"k_start", "joined", "m_bytes", "m_kind", "m_start",
+                          *metrics}
+                if group_by is not None:
+                    needed.add(group_by)
+                cols = {c: np.asarray(v)[sel] for c, v in cols.items()
+                        if c in needed}
+                rows_kept = int(sel.size)
+        ts = cols["k_start"].astype(np.int64)
+        if ts.size == 0:
+            # an empty (or fully filtered) shard contributes no rows and NO
+            # group keys
+            return ShardPartial(
+                idx=int(idx), n_bins=plan.n_shards,
+                bins=np.zeros(0, np.int64), group_keys=np.zeros(0, np.float64),
+                states={}, kind_keys=np.zeros(0, np.int64),
+                kind_bytes=np.zeros((0, plan.n_shards)),
+                rows_seen=rows_seen, rows_kept=rows_kept), None
+        vals = np.stack([np.asarray(cols[m], np.float64) for m in metrics],
+                        axis=0)
+        if group_by is None:
+            keys = np.asarray([_NO_GROUP_KEY])
+            gids = np.zeros(len(ts), np.int64)
+        else:
+            keys, gids = np.unique(np.asarray(cols[group_by], np.float64),
+                                   return_inverse=True)
+        bins, local_bin = _bounded_unique(plan.shard_of(ts), plan.n_shards)
+        kind_bytes: Dict[int, np.ndarray] = {}
+        _shard_kind_bytes(cols, plan, kind_bytes)
+        kinds = sorted(kind_bytes)
+        joined = cols["joined"] > 0 if "joined" in cols else np.zeros(0, bool)
+        m_start_hi = (int(cols["m_start"][joined].max())
+                      if joined.any() else -1)
+        sp = ShardPartial(
+            idx=int(idx), n_bins=plan.n_shards, bins=bins,
+            group_keys=np.asarray(keys, np.float64), states={},
+            kind_keys=np.asarray(kinds, np.int64),
+            kind_bytes=(np.stack([kind_bytes[k] for k in kinds]) if kinds
+                        else np.zeros((0, plan.n_shards))),
+            m_start_hi=m_start_hi, rows_seen=rows_seen, rows_kept=rows_kept)
+        return sp, (ts, vals, local_bin, gids)
 
 
 def compute_shard_partial(store: TraceStore, idx: int, plan: ShardPlan,
@@ -935,38 +938,44 @@ def compute_lane_partials_jax(store: TraceStore,
     for s in all_live:
         groups.setdefault(lanes[s[0]].reducers, []).append(s)
     for suite, live in groups.items():
-        m_max = max(len(lanes[li].metrics) for li, _, _, _ in live)
-        seg_sizes = [len(sp.bins) * len(sp.group_keys)
-                     for _, _, sp, _ in live]
-        seg_offs = np.concatenate([[0], np.cumsum(seg_sizes)])
-        n_seg = int(seg_offs[-1])
-        # segment count quantized up to a 128 multiple: the surplus
-        # segments receive no rows and are never sliced back, while the
-        # jitted collective (keyed on n_seg) gets reused across appends
-        # of similar shape instead of recompiling for every exact count
-        n_seg_dev = -(-max(n_seg, 1) // 128) * 128
-        seg_all = np.concatenate(
-            [local_bin * len(sp.group_keys) + gids + seg_offs[k]
-             for k, (_, _, sp, (_, _, local_bin, gids))
-             in enumerate(live)])
-        vals_parts = []
-        for _, _, _, rows in live:
-            v = rows[1]
-            if v.shape[0] < m_max:
-                v = np.pad(v, ((0, m_max - v.shape[0]), (0, 0)))
-            vals_parts.append(v)
-        vals_all = np.concatenate(vals_parts, axis=1)
-        row, valid = _slotwise_device_partition(
-            [len(rows[0]) for _, _, _, rows in live], len(devs))
-        seg_p = seg_all[row].astype(np.int32)
-        seg_p[~valid] = 0
+        with span("repro.reduce.stage") as stage:
+            m_max = max(len(lanes[li].metrics) for li, _, _, _ in live)
+            seg_sizes = [len(sp.bins) * len(sp.group_keys)
+                         for _, _, sp, _ in live]
+            seg_offs = np.concatenate([[0], np.cumsum(seg_sizes)])
+            n_seg = int(seg_offs[-1])
+            # segment count quantized up to a 128 multiple: the surplus
+            # segments receive no rows and are never sliced back, while
+            # the jitted collective (keyed on n_seg) gets reused across
+            # appends of similar shape instead of recompiling for every
+            # exact count
+            n_seg_dev = -(-max(n_seg, 1) // 128) * 128
+            seg_all = np.concatenate(
+                [local_bin * len(sp.group_keys) + gids + seg_offs[k]
+                 for k, (_, _, sp, (_, _, local_bin, gids))
+                 in enumerate(live)])
+            vals_parts = []
+            for _, _, _, rows in live:
+                v = rows[1]
+                if v.shape[0] < m_max:
+                    v = np.pad(v, ((0, m_max - v.shape[0]), (0, 0)))
+                vals_parts.append(v)
+            vals_all = np.concatenate(vals_parts, axis=1)
+            row, valid = _slotwise_device_partition(
+                [len(rows[0]) for _, _, _, rows in live], len(devs))
+            seg_p = seg_all[row].astype(np.int32)
+            seg_p[~valid] = 0
+            vals_p = vals_all[:, row].astype(np.float32)
+            stage.set(rows=int(seg_all.shape[0]), n_seg=n_seg,
+                      n_seg_dev=n_seg_dev)
         # ONE upload serves every reducer's collective (jnp.asarray
         # inside device_reduce is then a no-op); each device receives
         # only its own section, laid out as shard_map's in_specs expect
-        seg_j = jax.device_put(seg_p, rows_on)
-        vals_j = jax.device_put(vals_all[:, row].astype(np.float32),
-                                cols_on)
-        valid_j = jax.device_put(valid, rows_on)
+        with span("repro.reduce.h2d",
+                  bytes=seg_p.nbytes + vals_p.nbytes + valid.nbytes):
+            seg_j = jax.device_put(seg_p, rows_on)
+            vals_j = jax.device_put(vals_p, cols_on)
+            valid_j = jax.device_put(valid, rows_on)
         DEVICE_DISPATCHES.append(DeviceDispatch(
             reducers=tuple(suite), rows=int(valid.sum()), n_seg=n_seg,
             n_seg_dev=n_seg_dev,
@@ -1427,15 +1436,16 @@ def execute_plan(qplan: QueryPlan, use_cache: bool = True,
         for i in live:
             lane = qplan.lanes[i]
             computed = fresh.get(i, [])
-            all_keys, dense, kind_parts = _merge_lane(
-                lane_clean[i] + list(computed), qplan.n_shard_files,
-                qplan.n_ranks, lane.plan, len(lane.metrics),
-                lane.reducers)
-            result = finalize_aggregation(
-                store, lane.plan, list(lane.metrics), lane.query.group_by,
-                all_keys, dense, kind_parts,
-                lane.summary_key if use_cache else None, t0,
-                reducers=lane.reducers, covered=covered)
+            with span("repro.merge"):
+                all_keys, dense, kind_parts = _merge_lane(
+                    lane_clean[i] + list(computed), qplan.n_shard_files,
+                    qplan.n_ranks, lane.plan, len(lane.metrics),
+                    lane.reducers)
+                result = finalize_aggregation(
+                    store, lane.plan, list(lane.metrics),
+                    lane.query.group_by, all_keys, dense, kind_parts,
+                    lane.summary_key if use_cache else None, t0,
+                    reducers=lane.reducers, covered=covered)
             result.recomputed_shards = sorted(
                 int(s) for s in lane_dirty[i])
             result.partial_hits = len(lane_clean[i])

@@ -206,12 +206,12 @@ def _moments_flat_fn(n_seg: int, mesh: Mesh, axis: str):
     compiled callable on ``(n_seg, mesh, axis)`` and quantizing the
     caller's array shapes (see ``compute_partials_jax``) makes the
     steady-state append→delta loop hit jax's compilation cache instead."""
-    def rank_fn(seg, vals, vld):
+    def moments_rank_fn(seg, vals, vld):
         local = binstats_local(seg, vals, n_seg, valid=vld)
         return _collaborative_reduce(local, axis, mesh.shape[axis])
 
     spec = P(axis)
-    return jax.jit(jax.shard_map(rank_fn, mesh=mesh,
+    return jax.jit(jax.shard_map(moments_rank_fn, mesh=mesh,
                                  in_specs=(spec, P(None, axis), spec),
                                  out_specs=P(), check_vma=False))
 
@@ -246,7 +246,7 @@ def _histogram_flat_fn(n_seg: int, mesh: Mesh, axis: str):
     (same rationale as :func:`_moments_flat_fn`)."""
     n_all = n_seg * N_BUCKETS
 
-    def rank_fn(seg, vals, vld):
+    def histogram_rank_fn(seg, vals, vld):
         w = vld.astype(jnp.float32)
 
         def one_metric(v):
@@ -257,7 +257,7 @@ def _histogram_flat_fn(n_seg: int, mesh: Mesh, axis: str):
         return _collaborative_sum(local, axis, mesh.shape[axis], dim=1)
 
     spec = P(axis)
-    return jax.jit(jax.shard_map(rank_fn, mesh=mesh,
+    return jax.jit(jax.shard_map(histogram_rank_fn, mesh=mesh,
                                  in_specs=(spec, P(None, axis), spec),
                                  out_specs=P(), check_vma=False))
 

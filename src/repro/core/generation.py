@@ -34,6 +34,7 @@ from .events import EventTable, RankTrace
 from .query import Query
 from .sharding import (ShardPlan, assignment, contiguous_rank_range,
                        owner_of_shards)
+from .spans import span
 from .tracestore import StoreManifest, TraceStore
 
 # Columns each shard file carries: one row per JOINED (kernel x memcpy)
@@ -533,70 +534,78 @@ def run_append(db_paths: Sequence, out_dir: str,
     parts = []
     hi = man.t_end                      # plan end from INGESTED rows only
     for source in _resolve_sources(db_paths, cfg):
-        ap = source.path
-        # snapshot the NEW watermark before reading: rows a live profiler
-        # appends mid-read stay above it and are picked up by the NEXT
-        # append instead of being skipped forever
-        wm_new = source.rowid_hi()
-        known = ap in all_dbs
-        src = all_dbs.index(ap) if known else len(all_dbs)
-        wm = rowid_hi.get(ap) if known else None
-        if known and wm is None:
-            raise ValueError(
-                f"no ingest watermark recorded for known DB {ap!r} — "
-                "regenerate the store to make it appendable")
-        if not known:
-            all_dbs.append(ap)
-        source_kinds[ap] = source.schema.kind
-        if push_ranks is not None and src not in push_ranks:
-            # rank excluded by the recorded pushdown: never read events,
-            # but still advance the watermark (charging the in-range rows
-            # to the skipped counter) so later appends stay bounded
-            skipped = source.count_range(
-                min_rowids=tuple(wm) if wm else None, max_rowids=wm_new)
-            if skipped:
-                store._count("ingest_rows_skipped", skipped)
+        with span("repro.append.read") as read:
+            ap = source.path
+            # snapshot the NEW watermark before reading: rows a live profiler
+            # appends mid-read stay above it and are picked up by the NEXT
+            # append instead of being skipped forever
+            wm_new = source.rowid_hi()
+            known = ap in all_dbs
+            src = all_dbs.index(ap) if known else len(all_dbs)
+            wm = rowid_hi.get(ap) if known else None
+            if known and wm is None:
+                raise ValueError(
+                    f"no ingest watermark recorded for known DB {ap!r} — "
+                    "regenerate the store to make it appendable")
+            if not known:
+                all_dbs.append(ap)
+            source_kinds[ap] = source.schema.kind
+            if push_ranks is not None and src not in push_ranks:
+                # rank excluded by the recorded pushdown: never read events,
+                # but still advance the watermark (charging the in-range rows
+                # to the skipped counter) so later appends stay bounded
+                skipped = source.count_range(
+                    min_rowids=tuple(wm) if wm else None, max_rowids=wm_new)
+                if skipped:
+                    store._count("ingest_rows_skipped", skipped)
+                rowid_hi[ap] = list(wm_new)
+                continue
+            if known:
+                tr = source.read(rank=src, min_rowids=(wm[0], wm[1]),
+                                 max_rowids=wm_new, pushdown=pushdown,
+                                 count=store._count)
+                # Memcpy LOOK-BACK: a kernel appended THIS round may overlap
+                # transfers ingested by a PREVIOUS batch (rowid <= wm) within
+                # ``join_window_ns`` of the ingest boundary — re-fetch exactly
+                # those (time-bounded, rowid-capped: the kernel cap of 0 keeps
+                # old kernels out) so cross-batch matches are joined instead
+                # of silently dropped. Old kernels are never re-joined, so no
+                # duplicate rows can arise; the symmetric gap (an old kernel
+                # joining a NEWLY appended memcpy) would require rewriting
+                # committed rows and remains out of scope.
+                if len(tr.kernels) and wm[1] > 0:
+                    look = source.read(
+                        rank=src,
+                        start=int(tr.kernels.start.min()) - window,
+                        end=int(tr.kernels.end.max()) + window,
+                        max_rowids=(0, wm[1]), count=store._count)
+                    if len(look.memcpys):
+                        tr = RankTrace(
+                            rank=tr.rank, kernels=tr.kernels,
+                            memcpys=look.memcpys.concat(tr.memcpys),
+                            gpus=tr.gpus)
+            else:
+                tr = source.read(rank=src, max_rowids=wm_new,
+                                 pushdown=pushdown, count=store._count)
+            if len(tr.kernels) and int(tr.kernels.start.min()) < man.t_start:
+                raise ValueError(
+                    f"DB {ap!r} holds kernels before the store's t_start "
+                    f"({int(tr.kernels.start.min())} < {man.t_start}) — the "
+                    "plan only extends FORWARD (boundaries are immutable); "
+                    "regenerate to cover an earlier time range")
             rowid_hi[ap] = list(wm_new)
-            continue
-        if known:
-            tr = source.read(rank=src, min_rowids=(wm[0], wm[1]),
-                             max_rowids=wm_new, pushdown=pushdown,
-                             count=store._count)
-            # Memcpy LOOK-BACK: a kernel appended THIS round may overlap
-            # transfers ingested by a PREVIOUS batch (rowid <= wm) within
-            # ``join_window_ns`` of the ingest boundary — re-fetch exactly
-            # those (time-bounded, rowid-capped: the kernel cap of 0 keeps
-            # old kernels out) so cross-batch matches are joined instead
-            # of silently dropped. Old kernels are never re-joined, so no
-            # duplicate rows can arise; the symmetric gap (an old kernel
-            # joining a NEWLY appended memcpy) would require rewriting
-            # committed rows and remains out of scope.
-            if len(tr.kernels) and wm[1] > 0:
-                look = source.read(
-                    rank=src,
-                    start=int(tr.kernels.start.min()) - window,
-                    end=int(tr.kernels.end.max()) + window,
-                    max_rowids=(0, wm[1]), count=store._count)
-                if len(look.memcpys):
-                    tr = RankTrace(rank=tr.rank, kernels=tr.kernels,
-                                   memcpys=look.memcpys.concat(tr.memcpys),
-                                   gpus=tr.gpus)
-        else:
-            tr = source.read(rank=src, max_rowids=wm_new,
-                             pushdown=pushdown, count=store._count)
-        if len(tr.kernels) and int(tr.kernels.start.min()) < man.t_start:
-            raise ValueError(
-                f"DB {ap!r} holds kernels before the store's t_start "
-                f"({int(tr.kernels.start.min())} < {man.t_start}) — the "
-                "plan only extends FORWARD (boundaries are immutable); "
-                "regenerate to cover an earlier time range")
-        rowid_hi[ap] = list(wm_new)
-        if len(tr.kernels):
-            hi = max(hi, int(tr.kernels.end.max()))
+            if len(tr.kernels):
+                hi = max(hi, int(tr.kernels.end.max()))
+            read.set(rows=len(tr.kernels) + len(tr.memcpys))
         bw = {g.id: g.bandwidth for g in tr.gpus}
         sm = {g.id: g.sm_count for g in tr.gpus}
-        parts.append(window_left_join(tr.kernels, tr.memcpys, bw, sm,
-                                      window, cap, src_rank=src))
+        with span("repro.append.join") as join:
+            parts.append(window_left_join(tr.kernels, tr.memcpys, bw, sm,
+                                          window, cap, src_rank=src))
+            join.set(rows=len(parts[-1]["k_start"]))
+    # refresh the name table: appended rows can introduce new name ids
+    with span("repro.append.read"):
+        kernel_names = union_kernel_names(db_paths)
 
     # the plan extends exactly as far as the rows ingested THIS round —
     # deriving it from an unbounded range query would race a live writer
@@ -609,60 +618,62 @@ def run_append(db_paths: Sequence, out_dir: str,
             f"shards (> max_new_shards={max_new_shards}) — a far-future "
             "timestamp in the appended rows? Inspect the data or raise "
             "max_new_shards explicitly")
-    cols = _concat_columns(parts)
-    sid = plan.shard_of(cols["k_start"].astype(np.int64))
-    # ---- phase 1: PREPARE — stage every future shard, publish nothing
-    dirty: List[int] = []
-    appended = 0
-    staged: List[int] = []
-    for s in (np.unique(sid).tolist() if len(sid) else []):
-        mask = sid == s
-        new_cols = {c: cols[c][mask] for c in SHARD_COLUMNS}
-        if store.has_shard(int(s)):
-            old_cols = store.read_shard(int(s))
-            new_cols = {c: np.concatenate([old_cols[c], new_cols[c]])
-                        for c in SHARD_COLUMNS}
-            if s < man.n_shards:
-                dirty.append(int(s))
-        store.stage_shard(int(s), new_cols)
-        staged.append(int(s))
-        appended += int(mask.sum())
-    # every new shard index gets a file, empty ones included — same
-    # layout as a fresh generation
-    for s in range(man.n_shards, plan.n_shards):
-        if s not in staged and not store.has_shard(s):
-            store.stage_shard(
-                s, {c: np.zeros((0,), np.float64) for c in SHARD_COLUMNS})
+    with span("repro.append.stage") as stage:
+        cols = _concat_columns(parts)
+        sid = plan.shard_of(cols["k_start"].astype(np.int64))
+        # ---- phase 1: PREPARE — stage every future shard, publish nothing
+        dirty: List[int] = []
+        appended = 0
+        staged: List[int] = []
+        for s in (np.unique(sid).tolist() if len(sid) else []):
+            mask = sid == s
+            new_cols = {c: cols[c][mask] for c in SHARD_COLUMNS}
+            if store.has_shard(int(s)):
+                old_cols = store.read_shard(int(s))
+                new_cols = {c: np.concatenate([old_cols[c], new_cols[c]])
+                            for c in SHARD_COLUMNS}
+                if s < man.n_shards:
+                    dirty.append(int(s))
+            store.stage_shard(int(s), new_cols)
             staged.append(int(s))
+            appended += int(mask.sum())
+        # every new shard index gets a file, empty ones included — same
+        # layout as a fresh generation
+        for s in range(man.n_shards, plan.n_shards):
+            if s not in staged and not store.has_shard(s):
+                store.stage_shard(
+                    s, {c: np.zeros((0,), np.float64) for c in SHARD_COLUMNS})
+                staged.append(int(s))
+        stage.set(shards=len(staged), rows=appended)
 
-    owner = list(man.shard_owner) + [
-        int(i % max(man.n_ranks, 1))
-        for i in range(man.n_shards, plan.n_shards)]
-    extra = dict(man.extra)
-    extra["db_paths"] = all_dbs
-    extra["db_rowid_hi"] = rowid_hi
-    extra["source_kinds"] = source_kinds
-    # refresh the name table: appended rows can introduce new name ids
-    extra["kernel_names"] = {**dict(extra.get("kernel_names", {})),
-                             **union_kernel_names(db_paths)}
-    new_man = StoreManifest(
-        t_start=plan.t_start, t_end=plan.t_end, n_shards=plan.n_shards,
-        n_ranks=man.n_ranks, partitioning=man.partitioning,
-        columns=man.columns, shard_owner=owner, extra=extra)
-    # ---- phase 2: JOURNAL + COMMIT — from the journal write on, the
-    # append is committed: every staged rename below is idempotent and
-    # recover_append can replay the rest after a crash at ANY point
-    TraceStore._atomic_write(intent, json.dumps({
-        "version": APPEND_JOURNAL_VERSION,
-        "staged_shards": staged,
-        "manifest": new_man.to_json(),
-        "old_t_end": man.t_end, "new_t_end": plan.t_end,
-        "old_watermarks": man.extra["db_rowid_hi"],
-        "new_watermarks": rowid_hi}, indent=2).encode())
-    for s in staged:
-        store.commit_staged_shard(s)
-    store.write_manifest(new_man)
-    os.remove(intent)                    # append fully committed
+    with span("repro.append.commit", shards=len(staged)):
+        owner = list(man.shard_owner) + [
+            int(i % max(man.n_ranks, 1))
+            for i in range(man.n_shards, plan.n_shards)]
+        extra = dict(man.extra)
+        extra["db_paths"] = all_dbs
+        extra["db_rowid_hi"] = rowid_hi
+        extra["source_kinds"] = source_kinds
+        extra["kernel_names"] = {**dict(extra.get("kernel_names", {})),
+                                 **kernel_names}
+        new_man = StoreManifest(
+            t_start=plan.t_start, t_end=plan.t_end, n_shards=plan.n_shards,
+            n_ranks=man.n_ranks, partitioning=man.partitioning,
+            columns=man.columns, shard_owner=owner, extra=extra)
+        # ---- phase 2: JOURNAL + COMMIT — from the journal write on, the
+        # append is committed: every staged rename below is idempotent and
+        # recover_append can replay the rest after a crash at ANY point
+        TraceStore._atomic_write(intent, json.dumps({
+            "version": APPEND_JOURNAL_VERSION,
+            "staged_shards": staged,
+            "manifest": new_man.to_json(),
+            "old_t_end": man.t_end, "new_t_end": plan.t_end,
+            "old_watermarks": man.extra["db_rowid_hi"],
+            "new_watermarks": rowid_hi}, indent=2).encode())
+        for s in staged:
+            store.commit_staged_shard(s)
+        store.write_manifest(new_man)
+        os.remove(intent)                    # append fully committed
     return AppendReport(
         n_shards=plan.n_shards,
         n_new_shards=plan.n_shards - man.n_shards,

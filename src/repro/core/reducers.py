@@ -43,6 +43,8 @@ from typing import ClassVar, Dict, List, Sequence, Tuple, Type
 
 import numpy as np
 
+from .spans import span
+
 # --- quantile sketch bucketization constants -------------------------------
 # Fixed log2 buckets: bucket(v) = clip(floor(log2(max(v, V_FLOOR)) *
 # SUBDIV), 0, N_BUCKETS-1). SUBDIV buckets per octave; N_BUCKETS covers
@@ -67,6 +69,26 @@ def register_reducer(cls: Type["MergeableReducer"]):
     """Class decorator: register ``cls`` under ``cls.name``."""
     REDUCER_REGISTRY[cls.name] = cls
     return cls
+
+
+def _device_collective(reducer: str, collective, seg_ids, values,
+                       n_seg: int, mesh, valid) -> np.ndarray:
+    """Run one reducer's flat-segment device collective and copy its
+    result back, as the spans ``repro.reduce.dispatch`` (the call,
+    compiles of a new shape included) and ``repro.reduce.d2h`` (the wait
+    for the device and the copy). Returns the (n_seg, M, ...) table."""
+    import jax.numpy as jnp
+
+    with span("repro.reduce.dispatch", reducer=reducer,
+              rows_padded=int(seg_ids.shape[0]),
+              metrics=int(values.shape[0]), n_seg=int(n_seg),
+              devices=int(mesh.size)):
+        out = collective(jnp.asarray(seg_ids),
+                         jnp.asarray(values, jnp.float32), n_seg, mesh,
+                         valid=jnp.asarray(valid))
+    with span("repro.reduce.d2h", bytes=int(out.nbytes)):
+        table = np.asarray(out)
+    return np.moveaxis(table, 0, 1)
 
 
 def get_reducer(name: str) -> Type["MergeableReducer"]:
@@ -309,13 +331,10 @@ class BinStats(MergeableReducer):
     @classmethod
     def device_reduce(cls, seg_ids: np.ndarray, values: np.ndarray,
                       n_seg: int, mesh, valid: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
-
         from .distributed import distributed_moments_flat
-        out = distributed_moments_flat(
-            jnp.asarray(seg_ids), jnp.asarray(values, jnp.float32),
-            n_seg, mesh, valid=jnp.asarray(valid))
-        return np.moveaxis(np.asarray(out), 0, 1)   # (n_seg, M, 5)
+        return _device_collective(                   # (n_seg, M, 5)
+            cls.name, distributed_moments_flat, seg_ids, values, n_seg,
+            mesh, valid)
 
     @classmethod
     def from_device_block(cls, block: np.ndarray) -> "BinStats":
@@ -447,13 +466,10 @@ class QuantileSketch(MergeableReducer):
     @classmethod
     def device_reduce(cls, seg_ids: np.ndarray, values: np.ndarray,
                       n_seg: int, mesh, valid: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
-
         from .distributed import distributed_histogram_flat
-        out = distributed_histogram_flat(
-            jnp.asarray(seg_ids), jnp.asarray(values, jnp.float32),
-            n_seg, mesh, valid=jnp.asarray(valid))
-        return np.moveaxis(np.asarray(out), 0, 1)   # (n_seg, M, NB)
+        return _device_collective(                   # (n_seg, M, NB)
+            cls.name, distributed_histogram_flat, seg_ids, values, n_seg,
+            mesh, valid)
 
     @classmethod
     def from_device_block(cls, block: np.ndarray) -> "QuantileSketch":

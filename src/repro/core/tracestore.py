@@ -123,6 +123,7 @@ import numpy as np
 # SUMMARY_VERSION lives with the canonical query form (the cache keys
 # hash it); re-exported here because every payload reader stamps it.
 from .query import Query, SUMMARY_VERSION  # noqa: F401  (re-export)
+from .spans import span
 
 
 def shard_filename(idx: int) -> str:
@@ -310,7 +311,10 @@ class TraceStore:
     def read_shard(self, idx: int) -> Dict[str, np.ndarray]:
         path = os.path.join(self.root, shard_filename(idx))
         self._count("shard_reads")
-        return self._load_npz(path)
+        with span("repro.shard.read") as sp:
+            cols = self._load_npz(path)
+            sp.set(rows=len(cols.get("k_start", ())))
+        return cols
 
     def has_shard(self, idx: int) -> bool:
         return os.path.exists(os.path.join(self.root, shard_filename(idx)))
@@ -504,25 +508,26 @@ class TraceStore:
         path = self._pack_path(idx)
         if not payloads:
             return path
-        records = {}
-        for qkey, arrays in payloads.items():
-            meta = {}
-            if "version" in arrays:
-                meta["version"] = int(np.asarray(arrays["version"]))
-            if "fingerprint" in arrays:
-                meta["fingerprint"] = [
-                    int(x)
-                    for x in np.asarray(arrays["fingerprint"]).ravel()]
-            records[qkey] = (self._pack_arrays(arrays, meta).tobytes(),
-                             meta)
-        with self._pack_lock:
-            hit = self._load_pack(idx, want_raw=True)
-            entries = hit[1] if hit else None
-            if (entries is not None and hit[3] is not None
-                    and not set(records) & set(entries)):
-                self._append_pack(idx, path, hit, records)
-            else:
-                self._rewrite_pack(idx, path, hit, records)
+        with span("repro.partials.write"):
+            records = {}
+            for qkey, arrays in payloads.items():
+                meta = {}
+                if "version" in arrays:
+                    meta["version"] = int(np.asarray(arrays["version"]))
+                if "fingerprint" in arrays:
+                    meta["fingerprint"] = [
+                        int(x)
+                        for x in np.asarray(arrays["fingerprint"]).ravel()]
+                records[qkey] = (self._pack_arrays(arrays, meta).tobytes(),
+                                 meta)
+            with self._pack_lock:
+                hit = self._load_pack(idx, want_raw=True)
+                entries = hit[1] if hit else None
+                if (entries is not None and hit[3] is not None
+                        and not set(records) & set(entries)):
+                    self._append_pack(idx, path, hit, records)
+                else:
+                    self._rewrite_pack(idx, path, hit, records)
         self._count("partial_writes", len(records))
         return path
 
@@ -825,7 +830,8 @@ class TraceStore:
                       arrays: Dict[str, np.ndarray]) -> str:
         """Atomically persist one summary payload (see module docstring)."""
         path = os.path.join(self.root, summary_filename(key))
-        self._atomic_savez(path, arrays)
+        with span("repro.summary.write"):
+            self._atomic_savez(path, arrays)
         self._count("summary_writes")
         return path
 
@@ -842,6 +848,10 @@ class TraceStore:
         store), so a memo hit can never serve wrong data — it only
         skips a redundant np.load on the repeated per-tick probes a
         serving loop makes."""
+        with span("repro.summary.read"):
+            return self._read_summary(key)
+
+    def _read_summary(self, key: str) -> Optional[Dict[str, np.ndarray]]:
         path = os.path.join(self.root, summary_filename(key))
         try:
             sig_st = os.stat(path)

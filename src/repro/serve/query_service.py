@@ -154,6 +154,7 @@ from repro.core.anomaly import report_for_query
 from repro.core.generation import run_append
 from repro.core.query import Query, QueryPlan
 from repro.core.reducers import N_BUCKETS, QuantileSketch, bucket_of
+from repro.core.spans import TOTALS as SPAN_TOTALS, span
 from repro.core.tracestore import (TraceStore, pack_filename,
                                    summary_filename)
 from repro.serve.stream import IngestConfig, StreamIngestor
@@ -218,6 +219,8 @@ class _Pending:
     ingest_paths: Optional[List[str]] = None
     t_detect: float = 0.0               # event-to-fence latency anchor
     max_new_shards: int = 100_000
+    # admission wait anchor: monotonic time the request was enqueued
+    t_submit: float = dataclasses.field(default_factory=time.monotonic)
 
 
 class _Slot:
@@ -640,6 +643,14 @@ class QueryService:
         concurrently executing query ticks stay torn-free), THEN the
         fence lanes compile against the refreshed manifest and execute
         like any fused plan, touching only dirty/new shards."""
+        queued_ns = sum(int((tick.t_admit - p.t_submit) * 1e9)
+                        for p in tick.batch)
+        with span("repro.tick.exec", tick=tick.seq, kind=tick.kind,
+                  width=len(tick.flat), requests=len(tick.batch),
+                  queued_ns=queued_ns):
+            self._exec_tick_body(tick)
+
+    def _exec_tick_body(self, tick: _Tick) -> None:
         if tick.kind == "ingest":
             self._exec_ingest_append(tick)
             if tick.ingest_error is not None:
@@ -663,8 +674,10 @@ class QueryService:
                                     if ln.pruned is not None
                                     else set(range(qplan.n_shard_files)))
                 self.packs.register(tick.seq, tick.shards)
-                results = qplan.execute(use_cache=True,
-                                        pool=self.scan_pool)
+                with span("repro.tick.lanes", tick=tick.seq,
+                          kind=tick.kind):
+                    results = qplan.execute(use_cache=True,
+                                            pool=self.scan_pool)
                 for (q, slot), qr, lane in zip(tick.owned, results,
                                                qplan.lanes):
                     slot.qr = qr
@@ -683,33 +696,34 @@ class QueryService:
         for _, slot in tick.flat:
             if not slot.event.is_set():
                 slot.event.wait(max(0.0, deadline - time.monotonic()))
-        off = 0
-        for p in tick.batch:
-            body: List[Dict] = []
-            err = None
-            for q, slot in tick.flat[off:off + len(p.queries)]:
+        with span("repro.render"):
+            off = 0
+            for p in tick.batch:
+                body: List[Dict] = []
+                err = None
+                for q, slot in tick.flat[off:off + len(p.queries)]:
+                    if err is not None:
+                        continue
+                    if not slot.event.is_set():
+                        err = (503, "tick_timeout",
+                               "tick timed out waiting on an in-flight "
+                               "computation")
+                    elif slot.error is not None:
+                        err = slot.error
+                    else:
+                        qr = slot.qr
+                        hit = slot.owner_seq != tick.seq
+                        if qr.query is not q:
+                            qr = dataclasses.replace(qr, query=q)
+                        rendered = _render_result(qr)
+                        if hit:
+                            rendered["inflight_hit"] = True
+                        body.append(rendered)
+                off += len(p.queries)
                 if err is not None:
-                    continue
-                if not slot.event.is_set():
-                    err = (503, "tick_timeout",
-                           "tick timed out waiting on an in-flight "
-                           "computation")
-                elif slot.error is not None:
-                    err = slot.error
+                    p.error = err
                 else:
-                    qr = slot.qr
-                    hit = slot.owner_seq != tick.seq
-                    if qr.query is not q:
-                        qr = dataclasses.replace(qr, query=q)
-                    rendered = _render_result(qr)
-                    if hit:
-                        rendered["inflight_hit"] = True
-                    body.append(rendered)
-            off += len(p.queries)
-            if err is not None:
-                p.error = err
-            else:
-                p.results = body
+                    p.results = body
 
     def _exec_ingest_append(self, tick: _Tick) -> None:
         """The append half of an ingest tick: staged-commit
@@ -744,49 +758,52 @@ class QueryService:
         recency + evictions, service counters, in-flight slot retirement
         and the ``done`` events — serialized no matter how many ticks
         overlap, so eviction decisions and io bookkeeping never race."""
-        width = len(tick.flat)
-        self.ticks += 1
-        self.widths.append(width)
-        self._max_width = max(self._max_width, width)
-        self.inflight_hits += tick.borrowed
-        lat_ns = max((time.monotonic() - tick.t_admit) * 1e9, 1.0)
-        self._lat.counts[0, int(bucket_of(np.asarray([lat_ns]))[0])] += 1
-        keys = sorted({slot.summary_key for _, slot in tick.flat
-                       if slot.summary_key})
-        self.cache.touch(keys)
-        evicted = self.cache.evict()
-        self.packs.touch(sorted(tick.shards))
-        pack_evicted = self.packs.evict()
-        # unregister AFTER evicting: a committing tick's own keys stay
-        # immune through its own eviction pass
-        self.cache.unregister(tick.seq)
-        self.packs.unregister(tick.seq)
-        tick_info = {"fused_width": width,
-                     "batched_fused": width > 1,
-                     "n_requests": len(tick.batch),
-                     "inflight_hits": tick.borrowed,
-                     "evicted": evicted,
-                     "pack_evicted": pack_evicted}
-        tick.tick_info = tick_info
-        if tick.kind == "ingest":
-            tick_info["kind"] = "ingest"
-            # fence diff + hub publish BEFORE the done events: a caller
-            # whose ingest_once returns has its fence push guaranteed
-            # to be subscriber-visible already
-            if self.ingestor is not None:
-                self.ingestor.on_commit(tick)
-            tick_info.setdefault(
-                "ingest", tick.ingest
-                or {"error": tick.ingest_error})
-        for p in tick.batch:
-            p.tick_info = tick_info
-            p.done.set()
-        with self._inflight_lock:
-            for _, slot in tick.owned:
-                if self._inflight.get(slot.key) is slot:
-                    del self._inflight[slot.key]
-        if tick.release_sem:
-            self._depth_sem.release()
+        with span("repro.commit", tick=tick.seq):
+            width = len(tick.flat)
+            self.ticks += 1
+            self.widths.append(width)
+            self._max_width = max(self._max_width, width)
+            self.inflight_hits += tick.borrowed
+            lat_ns = max((time.monotonic() - tick.t_admit) * 1e9, 1.0)
+            self._lat.counts[0, int(bucket_of(np.asarray([lat_ns]))[0])] += 1
+            keys = sorted({slot.summary_key for _, slot in tick.flat
+                           if slot.summary_key})
+            self.cache.touch(keys)
+            with span("repro.commit.evict"):
+                evicted = self.cache.evict()
+                self.packs.touch(sorted(tick.shards))
+                pack_evicted = self.packs.evict()
+            # unregister AFTER evicting: a committing tick's own keys stay
+            # immune through its own eviction pass
+            self.cache.unregister(tick.seq)
+            self.packs.unregister(tick.seq)
+            tick_info = {"fused_width": width,
+                         "batched_fused": width > 1,
+                         "n_requests": len(tick.batch),
+                         "inflight_hits": tick.borrowed,
+                         "evicted": evicted,
+                         "pack_evicted": pack_evicted}
+            tick.tick_info = tick_info
+            if tick.kind == "ingest":
+                tick_info["kind"] = "ingest"
+                # fence diff + hub publish BEFORE the done events: a caller
+                # whose ingest_once returns has its fence push guaranteed
+                # to be subscriber-visible already
+                if self.ingestor is not None:
+                    with span("repro.fence.publish"):
+                        self.ingestor.on_commit(tick)
+                tick_info.setdefault(
+                    "ingest", tick.ingest
+                    or {"error": tick.ingest_error})
+            for p in tick.batch:
+                p.tick_info = tick_info
+                p.done.set()
+            with self._inflight_lock:
+                for _, slot in tick.owned:
+                    if self._inflight.get(slot.key) is slot:
+                        del self._inflight[slot.key]
+            if tick.release_sem:
+                self._depth_sem.release()
 
     # -- tick drivers ------------------------------------------------------
     def drain_once(self, block_s: float = 0.1) -> int:
@@ -920,6 +937,7 @@ class QueryService:
             "ingest_requests": self.ingest_requests,
             "ingest": (self.ingestor.stats()
                        if self.ingestor is not None else None),
+            "spans": SPAN_TOTALS.snapshot(),
         }
 
 
@@ -999,18 +1017,19 @@ def _make_handler(service: QueryService):
 
         def _send(self, code: int, payload: Dict,
                   deprecated: bool = False) -> None:
-            body = json.dumps(payload).encode()
-            self.send_response(code)
-            self.send_header("Content-Type", "application/json")
-            self.send_header("Content-Length", str(len(body)))
-            if deprecated:
-                path = urlparse(self.path).path.rstrip("/")
-                self.send_header("Deprecation", "true")
-                self.send_header(
-                    "Link", f'<{_LEGACY_ROUTES.get(path, path)}>; '
-                            'rel="successor-version"')
-            self.end_headers()
-            self.wfile.write(body)
+            with span("repro.respond"):
+                body = json.dumps(payload).encode()
+                self.send_response(code)
+                self.send_header("Content-Type", "application/json")
+                self.send_header("Content-Length", str(len(body)))
+                if deprecated:
+                    path = urlparse(self.path).path.rstrip("/")
+                    self.send_header("Deprecation", "true")
+                    self.send_header(
+                        "Link", f'<{_LEGACY_ROUTES.get(path, path)}>; '
+                                'rel="successor-version"')
+                self.end_headers()
+                self.wfile.write(body)
 
         def _fail(self, status: int, code: str, message: str,
                   detail=None, deprecated: bool = False) -> None:
