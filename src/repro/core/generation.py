@@ -22,6 +22,7 @@ table) instead of a row-at-a-time SQL loop — same result, columnar layout.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import os
@@ -184,11 +185,25 @@ def union_kernel_names(db_paths: Sequence) -> Dict[str, str]:
     """Union of every source's kernel-name table, JSON-manifest shaped
     (``{str(name_id): name}``). Conflicting spellings for one id resolve
     last-DB-wins — profiling ranks of one run share a build, so real
-    conflicts do not arise. Accepts paths or TraceSources."""
+    conflicts do not arise — but a ``kernel_{id}`` fallback never
+    replaces a spelling another DB's string table gives. Accepts paths
+    or TraceSources."""
     names: Dict[str, str] = {}
     for src in _resolve_sources(db_paths):
-        names.update({str(i): n for i, n in src.kernel_names().items()})
+        merge_kernel_names(names, src.kernel_names())
     return names
+
+
+def merge_kernel_names(into: Dict[str, str], names: Dict) -> Dict[str, str]:
+    """Merge one name table into a manifest-shaped one, in place: later
+    spellings win, except that a ``kernel_{id}`` fallback never replaces
+    a name already held. So the order in which appends and sources
+    deliver names does not change the result."""
+    for i, n in names.items():
+        key = str(i)
+        if n != f"kernel_{key}" or key not in into:
+            into[key] = n
+    return into
 
 
 def global_time_range(db_paths: Sequence) -> Tuple[int, int]:
@@ -532,6 +547,9 @@ def run_append(db_paths: Sequence, out_dir: str,
                   else {int(r) for r in pushdown.ranks})
 
     parts = []
+    # the name refresh: each source's string table plus fallbacks for the
+    # ids of the rows read this round — older ids are in the manifest
+    kernel_names: Dict[str, str] = {}
     hi = man.t_end                      # plan end from INGESTED rows only
     for source in _resolve_sources(db_paths, cfg):
         with span("repro.append.read") as read:
@@ -558,12 +576,24 @@ def run_append(db_paths: Sequence, out_dir: str,
                     min_rowids=tuple(wm) if wm else None, max_rowids=wm_new)
                 if skipped:
                     store._count("ingest_rows_skipped", skipped)
+                names, name_rows = source.window_names(
+                    min_rowids=tuple(wm) if wm else None, max_rowids=wm_new)
+                merge_kernel_names(kernel_names, names)
+                read.set(name_rows=name_rows)
                 rowid_hi[ap] = list(wm_new)
                 continue
+            # the new-rows read's window is the name refresh's: its rows
+            # plus those its pushdown skipped (counted SQL-side)
+            tally = collections.Counter()
+
+            def count(name: str, n: int) -> None:
+                store._count(name, n)
+                tally[name] += n
+
             if known:
                 tr = source.read(rank=src, min_rowids=(wm[0], wm[1]),
                                  max_rowids=wm_new, pushdown=pushdown,
-                                 count=store._count)
+                                 count=count)
                 # Memcpy LOOK-BACK: a kernel appended THIS round may overlap
                 # transfers ingested by a PREVIOUS batch (rowid <= wm) within
                 # ``join_window_ns`` of the ingest boundary — re-fetch exactly
@@ -583,10 +613,11 @@ def run_append(db_paths: Sequence, out_dir: str,
                         tr = RankTrace(
                             rank=tr.rank, kernels=tr.kernels,
                             memcpys=look.memcpys.concat(tr.memcpys),
-                            gpus=tr.gpus)
+                            gpus=tr.gpus, names=tr.names)
             else:
                 tr = source.read(rank=src, max_rowids=wm_new,
-                                 pushdown=pushdown, count=store._count)
+                                 pushdown=pushdown, count=count)
+            merge_kernel_names(kernel_names, tr.names)
             if len(tr.kernels) and int(tr.kernels.start.min()) < man.t_start:
                 raise ValueError(
                     f"DB {ap!r} holds kernels before the store's t_start "
@@ -596,16 +627,14 @@ def run_append(db_paths: Sequence, out_dir: str,
             rowid_hi[ap] = list(wm_new)
             if len(tr.kernels):
                 hi = max(hi, int(tr.kernels.end.max()))
-            read.set(rows=len(tr.kernels) + len(tr.memcpys))
+            read.set(rows=len(tr.kernels) + len(tr.memcpys),
+                     name_rows=len(tr.kernels) + tally["ingest_rows_skipped"])
         bw = {g.id: g.bandwidth for g in tr.gpus}
         sm = {g.id: g.sm_count for g in tr.gpus}
         with span("repro.append.join") as join:
             parts.append(window_left_join(tr.kernels, tr.memcpys, bw, sm,
                                           window, cap, src_rank=src))
             join.set(rows=len(parts[-1]["k_start"]))
-    # refresh the name table: appended rows can introduce new name ids
-    with span("repro.append.read"):
-        kernel_names = union_kernel_names(db_paths)
 
     # the plan extends exactly as far as the rows ingested THIS round —
     # deriving it from an unbounded range query would race a live writer
@@ -654,8 +683,8 @@ def run_append(db_paths: Sequence, out_dir: str,
         extra["db_paths"] = all_dbs
         extra["db_rowid_hi"] = rowid_hi
         extra["source_kinds"] = source_kinds
-        extra["kernel_names"] = {**dict(extra.get("kernel_names", {})),
-                                 **kernel_names}
+        extra["kernel_names"] = merge_kernel_names(
+            dict(extra.get("kernel_names", {})), kernel_names)
         new_man = StoreManifest(
             t_start=plan.t_start, t_end=plan.t_end, n_shards=plan.n_shards,
             n_ranks=man.n_ranks, partitioning=man.partitioning,

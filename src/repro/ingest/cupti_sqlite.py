@@ -52,6 +52,8 @@ import os
 import sqlite3
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+import numpy as np
+
 from repro.core.events import (EventTable, GpuInfo, RankTrace,
                                kernel_rows_to_table, memcpy_rows_to_table)
 from repro.core.query import Query
@@ -332,6 +334,12 @@ class SqliteTraceSource:
         ``("ingest_rows_skipped", n)`` for the rows the un-pushed read
         of the same range would have fetched but this one did not
         (counted SQL-side, never materialized).
+
+        ``names`` is the :meth:`kernel_names` table bounded to this
+        read's window: the whole string table, but ``kernel_{id}``
+        fallbacks only for the name ids of the window's kernel rows —
+        the rows an un-pushed read of the same range and rowids would
+        fetch — so a tail's read costs O(new rows), not O(table).
         """
         base_k, base_kp = self._range_clauses(
             start, end, None if max_rowids is None else max_rowids[0])
@@ -356,13 +364,16 @@ class SqliteTraceSource:
                 memcpys, m_read = EventTable.empty(), 0
             skipped = 0
             if push_k:
-                where = " AND ".join(base_k + ["rowid > ?"])
-                total = conn.execute(
-                    f"SELECT COUNT(*) FROM {self.schema.kernel_table} "
-                    f"WHERE {where}", base_kp + [min_k]).fetchone()[0]
-                skipped = max(0, int(total or 0) - k_read)
+                # the un-pushed window's name ids and row count, in one
+                # scan: ``names`` must not depend on the pushdown
+                ids, total = self._window_ids(conn, base_k, base_kp, min_k)
+                skipped = max(0, total - k_read)
+            elif self.schema.name_col is not None:
+                ids = np.unique(kernels.name_id).tolist()
+            else:
+                ids = []
             gpus = self._read_gpus(conn)
-            names = self._kernel_names(conn)
+            names = self._with_fallbacks(self._string_names(conn), ids)
         except sqlite3.DatabaseError as e:
             raise self._wrap(e) from None
         finally:
@@ -456,13 +467,54 @@ class SqliteTraceSource:
         bit-identical to native builds."""
         conn = self._connect()
         try:
-            return self._kernel_names(conn)
+            ids = []
+            if self.schema.name_col is not None:
+                ids = [nid for (nid,) in conn.execute(
+                    f"SELECT DISTINCT {self.schema.name_col} "
+                    f"FROM {self.schema.kernel_table}")]
+            return self._with_fallbacks(self._string_names(conn), ids)
         except sqlite3.DatabaseError as e:
             raise self._wrap(e) from None
         finally:
             conn.close()
 
-    def _kernel_names(self, conn) -> Dict[int, str]:
+    def window_names(self, min_rowids: Optional[Tuple[int, int]] = None,
+                     max_rowids: Optional[Tuple[int, int]] = None,
+                     ) -> Tuple[Dict[int, str], int]:
+        """The ``names`` an un-pushed :meth:`read` of this rowid window
+        would carry, and how many kernel rows their fallback ids came
+        from — one bounded scan, no event rows materialized. For a
+        source whose events a ``ranks`` pushdown skips."""
+        min_k = int(min_rowids[0]) if min_rowids is not None else 0
+        clauses, params = self._range_clauses(
+            None, None, None if max_rowids is None else max_rowids[0])
+        conn = self._connect()
+        try:
+            ids, n = self._window_ids(conn, clauses, params, min_k)
+            return self._with_fallbacks(self._string_names(conn), ids), n
+        except sqlite3.DatabaseError as e:
+            raise self._wrap(e) from None
+        finally:
+            conn.close()
+
+    def _window_ids(self, conn, clauses: List[str], params: List,
+                    min_rowid: int) -> Tuple[List, int]:
+        """(distinct kernel-name ids, kernel rows) of one rowid window."""
+        s = self.schema
+        where = " AND ".join(clauses + ["rowid > ?"])
+        if s.name_col is None:
+            n = conn.execute(f"SELECT COUNT(*) FROM {s.kernel_table} "
+                             f"WHERE {where}", params + [min_rowid])
+            return [], int(n.fetchone()[0] or 0)
+        rows = conn.execute(
+            f"SELECT {s.name_col}, COUNT(*) FROM {s.kernel_table} "
+            f"WHERE {where} GROUP BY {s.name_col}",
+            params + [min_rowid]).fetchall()
+        return [r[0] for r in rows], sum(int(r[1]) for r in rows)
+
+    def _string_names(self, conn) -> Dict[int, str]:
+        """The string table, minus GPU-inventory name refs when the
+        device table indexes into it (real nvprof)."""
         s = self.schema
         names: Dict[int, str] = {}
         if s.string_table is not None:
@@ -473,11 +525,13 @@ class SqliteTraceSource:
                     f"SELECT DISTINCT name FROM {s.device_table}"):
                 if nid is not None:
                     names.pop(int(nid), None)
-        if s.name_col is not None:
-            for (nid,) in conn.execute(
-                    f"SELECT DISTINCT {s.name_col} FROM {s.kernel_table}"):
-                if nid is not None and int(nid) not in names:
-                    names[int(nid)] = f"kernel_{int(nid)}"
+        return names
+
+    @staticmethod
+    def _with_fallbacks(names: Dict[int, str], ids) -> Dict[int, str]:
+        for nid in ids:
+            if nid is not None and int(nid) not in names:
+                names[int(nid)] = f"kernel_{int(nid)}"
         return names
 
     def _read_gpus(self, conn) -> List[GpuInfo]:

@@ -8,6 +8,7 @@ exports, name-table spelling tolerance with ``kernel_{id}`` fallback,
 streaming tails of a live-written Nsight export, and the diff engine
 running against two ingested real-trace stores."""
 
+import dataclasses
 import os
 import sqlite3
 import time
@@ -20,7 +21,8 @@ from repro.core import (GenerationConfig, PipelineConfig, Query,
                         generate_synthetic, inject_slowdown,
                         run_aggregation, run_generation, trace_remainder,
                         truncate_trace, write_synthetic_dbs)
-from repro.core.events import read_kernel_names
+from repro.core.events import (RankTrace, append_rank_db, read_kernel_names,
+                               write_rank_db)
 from repro.ingest import (IngestError, SqliteTraceSource,
                           append_fixture_rank_db, as_trace_source,
                           rowid_watermark, sniff_schema, write_fixture_dbs,
@@ -284,6 +286,111 @@ def test_missing_name_rows_fall_back_to_kernel_id(trio, tmp_path, flavor):
     run_generation([p], out, n_ranks=1)
     man = TraceStore(out).read_manifest()
     assert man.extra["kernel_names"]["3"] == "kernel_3"
+
+
+def _renamed(tr, ids_where, new_id):
+    """``tr`` with the kernels ``ids_where`` selects renamed ``new_id``."""
+    ids = np.where(ids_where, new_id, tr.kernels.name_id).astype(np.int32)
+    return RankTrace(rank=tr.rank,
+                     kernels=dataclasses.replace(tr.kernels, name_id=ids),
+                     memcpys=tr.memcpys, gpus=tr.gpus,
+                     names={**tr.names, new_id: f"late_{new_id}"})
+
+
+def test_read_names_are_bounded_to_its_window(trio, tmp_path):
+    """``read().names``: the whole string table (as ``kernel_names()``
+    has it), but ``kernel_{id}`` fallbacks only for ids of the kernel
+    rows in the read's window — with or without a pushdown, which never
+    changes the set."""
+    ds = trio[0]
+    tr = ds.traces[0]
+    half = len(tr.kernels) // 2
+    # id 5 only in the first half of the rows: outside the window below
+    tr = _renamed(tr, (np.arange(len(tr.kernels)) >= half)
+                  & (tr.kernels.name_id == 5), 6)
+    assert 3 in tr.kernels.name_id[half:]
+    p = str(tmp_path / "lossy.sqlite")
+    write_nsys_rank_db(p, tr, drop_name_ids=(3, 5))
+    src = SqliteTraceSource.open(p)
+    full = src.kernel_names()
+    assert full[3] == "kernel_3" and full[5] == "kernel_5"
+    strings = {i: n for i, n in full.items() if i not in (3, 5)}
+
+    assert src.read(rank=0).names == full
+    for pushdown in (None, Query(kernel_names=(0, 1))):
+        names = src.read(rank=0, min_rowids=(half, 0),
+                         pushdown=pushdown).names
+        assert names[3] == "kernel_3"       # referenced in the window
+        assert 5 not in names               # referenced only before it
+        assert {i: n for i, n in names.items() if i != 3} == strings
+        assert src.window_names(min_rowids=(half, 0)) == (
+            names, len(tr.kernels) - half)
+
+
+_LATE_ID = 4242
+
+# case -> (flavor, recorded pushdown, ids dropped from the string table,
+#          whether only the last rank's export drops them)
+_APPEND_NAME_CASES = {
+    "native": ("native", None, (), False),
+    "nvprof": ("nvprof", None, (), False),
+    "nsys": ("nsys", None, (), False),
+    "lossy": ("nsys", None, (3, _LATE_ID), False),
+    "lossy_last_rank": ("nsys", None, (3, _LATE_ID), True),
+    "kernel_names_pushdown": (
+        "nvprof", Query(kernel_names=tuple(range(8))), (3, _LATE_ID), False),
+    "ranks_pushdown": ("nvprof", Query(ranks=(0,)), (3, _LATE_ID), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_APPEND_NAME_CASES))
+def test_appended_kernel_names_match_cold_generation(trio, tmp_path, case):
+    """After two appends, the manifest's ``kernel_names`` equals a cold
+    generation's over the grown DBs, though the append reads names only
+    for the rows it ingests. The last rank's second append brings an id
+    no earlier row uses (lossy cases drop it from the string table, and
+    the pushdown cases keep its rows out of the reads). Where only one
+    export lacks a name, the other's spelling wins over the fallback."""
+    from repro.core import run_append
+    ds = trio[0]
+    flavor, pushdown, drop_ids, last_only = _APPEND_NAME_CASES[case]
+    drops = [() if last_only and tr.rank != ds.traces[-1].rank
+             else drop_ids for tr in ds.traces]
+    t0 = (int(ds.traces[0].kernels.start.min()) // _NS) * _NS
+    c1, c2 = t0 + 8 * _NS, t0 + 12 * _NS
+    traces = list(ds.traces)
+    last = traces[-1]
+    traces[-1] = _renamed(last, (last.kernels.end > c2)
+                          & (np.arange(len(last.kernels)) % 7 == 0),
+                          _LATE_ID)
+    paths = [str(tmp_path / f"rank{tr.rank}.sqlite") for tr in traces]
+    for tr, p, drop in zip(traces, paths, drops):
+        if flavor == "native":
+            write_rank_db(p, truncate_trace(tr, c1))
+        else:
+            writer = (write_nvprof_rank_db if flavor == "nvprof"
+                      else write_nsys_rank_db)
+            writer(p, truncate_trace(tr, c1), drop_name_ids=drop)
+    cfg = GenerationConfig(pushdown=pushdown)
+    out = str(tmp_path / "store")
+    run_generation(paths, out, n_ranks=2, cfg=cfg)
+    for part in (lambda tr: truncate_trace(trace_remainder(tr, c1), c2),
+                 lambda tr: trace_remainder(tr, c2)):
+        for tr, p, drop in zip(traces, paths, drops):
+            if flavor == "native":
+                append_rank_db(p, part(tr))
+            else:
+                append_fixture_rank_db(p, part(tr), flavor=flavor,
+                                       drop_name_ids=drop)
+        run_append(paths, out)
+    cold = str(tmp_path / "cold")
+    run_generation(paths, cold, n_ranks=2, cfg=cfg)
+    got = TraceStore(out).read_manifest().extra["kernel_names"]
+    want = TraceStore(cold).read_manifest().extra["kernel_names"]
+    assert got == want
+    assert want[str(_LATE_ID)] == (
+        "kernel_4242" if _LATE_ID in drop_ids else "late_4242")
+    assert (want["3"] == "kernel_3") == (3 in drop_ids and not last_only)
 
 
 def test_rowid_watermark_dialect_aware(trio):

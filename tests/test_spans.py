@@ -13,8 +13,8 @@ import urllib.request
 
 import pytest
 
-from repro.core import (append_rank_db, run_generation, trace_remainder,
-                        truncate_trace, write_rank_db)
+from repro.core import (append_rank_db, run_append, run_generation,
+                        trace_remainder, truncate_trace, write_rank_db)
 from repro.core.events import SyntheticSpec, generate_synthetic
 from repro.core.spans import TOTALS, span
 from repro.serve.query_service import QueryService, ServiceConfig
@@ -188,8 +188,8 @@ def test_stats_route_carries_the_span_totals(store_dir, tmp_path):
 
 def test_ingest_tick_spans_its_append_phases(tmp_path):
     """One ingest tick records each phase of its append (a read and a
-    join per rank DB, the kernel-name read, one stage, one commit), its
-    fence lanes and the fence publication."""
+    join per rank DB, one stage, one commit), its fence lanes and the
+    fence publication."""
     ds = generate_synthetic(SyntheticSpec(
         n_ranks=2, kernels_per_rank=1500, memcpys_per_rank=200,
         duration_s=8.0, seed=13))
@@ -217,7 +217,7 @@ def test_ingest_tick_spans_its_append_phases(tmp_path):
     def delta(name, key="count"):
         return got[name][key] - before.get(name, {key: 0})[key]
 
-    assert delta("repro.append.read") == len(paths) + 1
+    assert delta("repro.append.read") == len(paths)
     assert delta("repro.append.join") == len(paths)
     assert delta("repro.append.stage") == delta("repro.append.commit") == 1
     assert (got["repro.append.join"]["stats"]["rows"]
@@ -226,3 +226,29 @@ def test_ingest_tick_spans_its_append_phases(tmp_path):
     for name in ("repro.tick.exec", "repro.tick.lanes", "repro.commit",
                  "repro.fence.publish"):
         assert delta(name) == 1, name
+
+
+def test_append_name_refresh_reads_only_the_new_rows(tmp_path):
+    """The kernel-name refresh of an append draws its fallback ids from
+    the N kernel rows it ingests, not from the M + N the DB then holds:
+    the ``name_rows`` stat of ``repro.append.read`` is N."""
+    ds = generate_synthetic(SyntheticSpec(
+        n_ranks=1, kernels_per_rank=3000, memcpys_per_rank=200,
+        duration_s=8.0, seed=17))
+    tr = ds.traces[0]
+    cutoff = int(tr.kernels.end.max()) - 2 * 10**8
+    p = str(tmp_path / "rank0.sqlite")
+    write_rank_db(p, truncate_trace(tr, cutoff))
+    store = str(tmp_path / "store")
+    run_generation([p], store, n_ranks=1)
+    tail = trace_remainder(tr, cutoff)
+    n, m = len(tail.kernels), len(tr.kernels) - len(tail.kernels)
+    assert 0 < 10 * n < m
+    append_rank_db(p, tail)
+    before = TOTALS.snapshot().get("repro.append.read",
+                                   {"count": 0, "stats": {}})
+    run_append([p], store)
+    got = TOTALS.snapshot()["repro.append.read"]
+    assert got["count"] - before["count"] == 1
+    assert (got["stats"]["name_rows"]
+            - before["stats"].get("name_rows", 0)) == n
