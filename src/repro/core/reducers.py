@@ -76,16 +76,23 @@ def _device_collective(reducer: str, collective, seg_ids, values,
     """Run one reducer's flat-segment device collective and copy its
     result back, as the spans ``repro.reduce.dispatch`` (the call,
     compiles of a new shape included) and ``repro.reduce.d2h`` (the wait
-    for the device and the copy). Returns the (n_seg, M, ...) table."""
+    for the device and the copy). Returns the (n_seg, M, ...) table.
+
+    The dispatch's ``exchange_bytes`` stat is what each chip puts into
+    the exchange between chips: the reduced table's bytes on a mesh of
+    several devices, 0 on one. It is read off the output's shape, so it
+    waits for nothing."""
     import jax.numpy as jnp
 
     with span("repro.reduce.dispatch", reducer=reducer,
               rows_padded=int(seg_ids.shape[0]),
               metrics=int(values.shape[0]), n_seg=int(n_seg),
-              devices=int(mesh.size)):
+              devices=int(mesh.size)) as dispatch:
         out = collective(jnp.asarray(seg_ids),
                          jnp.asarray(values, jnp.float32), n_seg, mesh,
                          valid=jnp.asarray(valid))
+        dispatch.set(exchange_bytes=int(out.nbytes) if mesh.size > 1
+                     else 0)
     with span("repro.reduce.d2h", bytes=int(out.nbytes)):
         table = np.asarray(out)
     return np.moveaxis(table, 0, 1)

@@ -1,6 +1,7 @@
 """The device collectives compiled for a described (unattached) TPU v5e:
 one chip and a 2x2 mesh, at the padded sizes of chip_smoke.py's two
-dispatches (its 4-rank store at the paper's density). The TPU compiler
+dispatches (its 4-rank store at the paper's density) and of the largest
+dispatches of the served eight-rank capture on four chips. The TPU compiler
 runs here without the chip, so what it would refuse, or what would not
 fit the chip's 16 GB, fails here first.
 
@@ -25,7 +26,13 @@ V5E_HBM_BYTES = 16 * 1024 ** 3
 # runs the moments collective too
 CASES = [("moments", 1 << 23, 2, 640),
          ("moments", 1 << 24, 1, 7808),
-         ("histogram", 1 << 24, 1, 7808)]
+         ("histogram", 1 << 24, 1, 7808),
+         # the paper8.explore_cold cell's largest moments dispatch and its
+         # largest histogram dispatches (by rows, by segments), from the
+         # repro.reduce.dispatch stats of a traced run on a 2x2 v5e
+         ("moments", 1 << 23, 3, 1920),
+         ("histogram", 1 << 22, 3, 768),
+         ("histogram", 1 << 21, 3, 1024)]
 
 
 @pytest.fixture(scope="module")

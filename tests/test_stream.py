@@ -289,10 +289,13 @@ def test_fence_push_received_over_http(growing, tmp_path):
         assert e["kind"] in ("fence", "ingest")
         assert e["ingest"]["rows_ingested"] > 0
         assert body["next_since"] >= e["seq"]
-        # caught up: a fresh long poll with a short timeout is empty
-        again = c.fences(since=body["next_since"], timeout_s=0.2)
-        assert again["events"] == []
+        # caught up: once every written row is in, a fresh long poll
+        # past the last event is empty (the two rank DBs' writes may
+        # land in two ticks, so the rest is drained first)
         assert svc.ingestor.quiesce(timeout_s=60.0)
+        rest = c.fences(since=body["next_since"], timeout_s=0.2)
+        again = c.fences(since=rest["next_since"], timeout_s=0.2)
+        assert again["events"] == []
         st = c.stats()["ingest"]
         assert st["rows_ingested"] > 0
         assert st["event_to_fence_p99_ms"] > 0.0
