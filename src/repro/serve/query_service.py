@@ -55,7 +55,13 @@ Shared summary cache + byte-budgeted LRU eviction
     ``summary_budget_bytes``, deletes least-recently-used summary files
     — but NEVER a key registered by ANY in-flight tick (widened from
     "current tick" when ticks began to overlap), so a result is never
-    evicted between being computed and being read back.
+    evicted between being computed and being read back. The store's
+    size comes from a ledger of per-summary bytes, listed once at start
+    and then charged with one stat per summary a tick wrote: a tick
+    served from summaries alone makes no syscall here. The store is
+    listed again, and every eviction decided on that listing, only
+    when the ledger passes the budget or the tick is an ingest tick
+    (its manifest write sweeps stale summaries).
 
 Pack LRU (partial-pack byte budget)
     ``pack_budget_bytes`` extends the same byte-budget discipline to
@@ -276,6 +282,11 @@ class _ByteBudgetLRU:
         with self._reg_lock:
             self._inflight[tick_seq] = set(keys)
 
+    def registered(self, tick_seq: int) -> set:
+        """The keys tick ``tick_seq`` registered (empty if none)."""
+        with self._reg_lock:
+            return set(self._inflight.get(tick_seq, ()))
+
     def unregister(self, tick_seq: int) -> None:
         with self._reg_lock:
             self._inflight.pop(tick_seq, None)
@@ -316,28 +327,72 @@ class SummaryCacheLRU(_ByteBudgetLRU):
     many ticks overlap). Summary files that appear out of band (another
     process, a pre-existing store) are adopted at the cold end of the
     order. Evicting a summary is always safe: it is derived data,
-    recomputable from shards/partials at the cost of one scan."""
+    recomputable from shards/partials at the cost of one scan.
+
+    The store's size is a ledger of bytes per key, not a listing per
+    commit. A budgeted cache lists and stats the store once when it is
+    made (adopting what it finds); each commit then stats only the
+    summaries its tick wrote, and returns at once while the ledger is
+    within budget. The store is listed again, the ledger replaced by
+    what it finds and every eviction decided on that listing, when the
+    ledger passes the budget or the caller says the store changed
+    behind it (an ingest tick: its manifest write sweeps stale
+    summaries). The ledger never understates a store that only the
+    service writes, so a within-budget ledger means a within-budget
+    store; a ledger that overstates (summaries deleted out of band)
+    corrects itself the first time it crosses. ``rescans`` counts the
+    commits that listed the store."""
 
     def __init__(self, store: TraceStore,
                  budget_bytes: Optional[int]) -> None:
         super().__init__(budget_bytes)
         self.store = store
+        self._sizes: Dict[str, int] = {}
+        self.rescans = 0
+        if self.budget:
+            self._rescan()
 
-    def evict(self) -> int:
-        """Delete LRU summary files until the store fits the budget.
-        Returns how many were evicted (0 when unbudgeted or within).
-        Commit-stage only (single caller at a time)."""
-        if not self.budget:
-            return 0
+    def _path(self, key: str) -> str:
+        return os.path.join(self.store.root, summary_filename(key))
+
+    def _rescan(self) -> int:
+        """Replace the ledger by a listing of the store with sizes;
+        returns the syscalls made."""
+        keys = self.store.summary_keys()
         sizes: Dict[str, int] = {}
-        for k in self.store.summary_keys():
+        for k in keys:
             try:
-                sizes[k] = os.path.getsize(
-                    os.path.join(self.store.root, summary_filename(k)))
+                sizes[k] = os.path.getsize(self._path(k))
             except OSError:
                 pass
+        self._sizes = sizes
         self._sync_order(sizes)
-        total = sum(sizes.values())
+        return 1 + len(keys)
+
+    def evict(self, written=(), rescan: bool = False
+              ) -> Tuple[int, bool, int]:
+        """Charge the ledger with ``written`` (the summary keys the
+        committing tick wrote), then, if the ledger passes the budget
+        or ``rescan`` is set, list the store and delete LRU summary
+        files until it fits. Returns ``(evicted, listed, stat_calls)``:
+        the files deleted, whether the store was listed, and the
+        listing and stat calls made. Commit-stage only (single caller
+        at a time)."""
+        if not self.budget:
+            return 0, False, 0
+        stat_calls = 0
+        for k in written:
+            stat_calls += 1
+            try:
+                self._sizes[k] = os.path.getsize(self._path(k))
+            except OSError:
+                self._sizes.pop(k, None)
+        total = sum(self._sizes.values())
+        if total <= self.budget and not rescan:
+            return 0, False, stat_calls
+        self.rescans += 1
+        stat_calls += self._rescan()
+        total = sum(self._sizes.values())
         immune = self.immune()
         evicted = 0
         for k in list(self._order):
@@ -346,15 +401,14 @@ class SummaryCacheLRU(_ByteBudgetLRU):
             if k in immune:
                 continue                 # in-flight tick reads this key
             try:
-                os.remove(os.path.join(self.store.root,
-                                       summary_filename(k)))
+                os.remove(self._path(k))
             except FileNotFoundError:
                 pass
-            total -= sizes[k]
+            total -= self._sizes.pop(k)
             self._order.pop(k)
             evicted += 1
         self.evictions += evicted
-        return evicted
+        return evicted, True, stat_calls
 
 
 class PackCacheLRU(_ByteBudgetLRU):
@@ -769,8 +823,15 @@ class QueryService:
             keys = sorted({slot.summary_key for _, slot in tick.flat
                            if slot.summary_key})
             self.cache.touch(keys)
-            with span("repro.commit.evict"):
-                evicted = self.cache.evict()
+            # what the tick wrote: every summary key it registered but
+            # did not serve as a summary hit (all of them, if it failed)
+            hits = {slot.summary_key for _, slot in tick.owned
+                    if slot.qr is not None and slot.qr.cache_hit}
+            written = sorted(self.cache.registered(tick.seq) - hits)
+            with span("repro.commit.evict") as sp:
+                evicted, listed, stat_calls = self.cache.evict(
+                    written, rescan=tick.kind == "ingest")
+                sp.set(rescan=int(listed), stat_calls=stat_calls)
                 self.packs.touch(sorted(tick.shards))
                 pack_evicted = self.packs.evict()
             # unregister AFTER evicting: a committing tick's own keys stay
@@ -931,6 +992,7 @@ class QueryService:
             "pipeline_depth": self._depth,
             "scan": self.scan_pool.utilization(),
             "evictions": self.cache.evictions,
+            "summary_rescans": self.cache.rescans,
             "pack_evictions": self.packs.evictions,
             "pack_compactions": self.packs.compactions,
             "io_counts": dict(self.store.io_counts),

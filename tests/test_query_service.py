@@ -4,7 +4,9 @@ requests drain into ONE fused plan), the per-request result-size budget
 (HTTP 413), the byte-budgeted summary LRU — which must never evict a
 key touched within the current tick — and the concurrency layer:
 parallel-scan bit-identity, overlapping-tick in-flight dedup, the
-dead-worker 503/tick_timeout contract, and the pack-byte-budget LRU."""
+dead-worker 503/tick_timeout contract, and the pack-byte-budget LRU.
+The summary LRU's size ledger is checked against the rule it replaced,
+a listing of the whole store on every commit."""
 
 import json
 import os
@@ -17,13 +19,14 @@ import urllib.request
 import numpy as np
 import pytest
 
-from repro.core import (Query, SyntheticSpec, TraceStore,
-                        generate_synthetic, run_generation, run_queries,
-                        write_rank_db)
+from repro.core import (Query, QueryPlan, SyntheticSpec, TraceStore,
+                        append_rank_db, generate_synthetic,
+                        run_generation, run_queries, trace_remainder,
+                        truncate_trace, write_rank_db)
 from repro.core.aggregation import ScanPool
 from repro.core.tracestore import summary_filename
 from repro.serve.query_service import (BudgetExceeded, QueryService,
-                                       ServiceConfig)
+                                       ServiceConfig, _ByteBudgetLRU)
 
 
 @pytest.fixture(scope="module")
@@ -318,3 +321,135 @@ def test_pack_budget_evicts_only_committed_ticks_packs(store_dir):
     assert p.error is None
     assert p.results[0]["groups"] == first["groups"]
     assert p.results[0]["n_samples"] == first["n_samples"]
+
+
+# --- the summary LRU's size ledger against the full-listing rule ------------
+
+class _FullListingLRU(_ByteBudgetLRU):
+    """The reference rule: list and stat every summary file on every
+    commit, then evict least-recently-used, non-immune keys until the
+    listed total fits the budget."""
+
+    def __init__(self, store, budget_bytes):
+        super().__init__(budget_bytes)
+        self.store = store
+
+    def evict(self, written=(), rescan=False):
+        sizes = {k: os.path.getsize(os.path.join(self.store.root,
+                                                 summary_filename(k)))
+                 for k in self.store.summary_keys()}
+        self._sync_order(sizes)
+        total = sum(sizes.values())
+        immune = self.immune()
+        evicted = 0
+        for k in list(self._order):
+            if total <= self.budget:
+                break
+            if k in immune:
+                continue
+            os.remove(os.path.join(self.store.root, summary_filename(k)))
+            total -= sizes.pop(k)
+            self._order.pop(k)
+            evicted += 1
+        self.evictions += evicted
+        return evicted, True, 1 + len(sizes)
+
+
+def _disk_sizes(store):
+    return {k: os.path.getsize(os.path.join(store.root,
+                                            summary_filename(k)))
+            for k in store.summary_keys()}
+
+
+@pytest.fixture(scope="module")
+def live_ds():
+    ds = generate_synthetic(SyntheticSpec(
+        n_ranks=2, kernels_per_rank=1500, memcpys_per_rank=200,
+        duration_s=8.0, seed=13))
+    return ds, int(ds.traces[0].kernels.start.min()) + 4 * 10**9
+
+
+@pytest.mark.parametrize("budget", [1, 12_000, 10**9])
+def test_ledger_lru_evicts_what_the_full_listing_rule_evicts(
+        live_ds, tmp_path, budget):
+    """Two services on copies of one store run the same ticks: one with
+    the size ledger, one with the full listing on every commit. They
+    evict the same keys at every commit and keep the same LRU order,
+    through a budget crossed again and again (summaries of 4-17 KB), a
+    summary planted before start (adopted at the cold end), same-tick
+    immunity, an out-of-band ``clear_summaries`` (the ledger overstates,
+    then corrects) and an ingest tick (which lists the store). After
+    every commit the ledger never understates the store, and the store
+    fits the budget unless only the committing tick's keys remain."""
+    ds, cutoff = live_ds
+    paths = []
+    for tr in ds.traces:
+        p = str(tmp_path / f"rank{tr.rank}.sqlite")
+        write_rank_db(p, truncate_trace(tr, cutoff))
+        paths.append(p)
+    built = str(tmp_path / "built")
+    run_generation(paths, built, n_ranks=2)
+    planted = "0" * 16
+    with open(os.path.join(built, summary_filename(planted)), "wb") as f:
+        f.write(b"\0" * 4000)
+    cfg = dict(tick_ms=1.0, summary_budget_bytes=budget)
+    led = QueryService(shutil.copytree(built, str(tmp_path / "led")),
+                       ServiceConfig(**cfg))
+    ref = QueryService(shutil.copytree(built, str(tmp_path / "ref")),
+                       ServiceConfig(**cfg))
+    ref.cache = _FullListingLRU(ref.store, budget)
+    assert led.cache._sizes == {planted: 4000}
+
+    qa = Query(metrics=("k_stall",), group_by="m_kind")
+    qb = Query(metrics=("m_duration",), group_by="m_kind")
+    qc = Query(metrics=("m_bytes",))
+    qd = Query(metrics=("k_stall", "m_duration"), group_by="k_device")
+    qe = Query(metrics=("k_stall",), anomaly_score="p99")
+    steps = [[qa], [qb], [qa], [qc, qd], [qa, qb], "clear", [qe], [qa],
+             [qb], "append", "ingest", [qc], [qa], [qd], [qa]]
+    for i, step in enumerate(steps):
+        if step == "clear":
+            led.store.clear_summaries()
+            ref.store.clear_summaries()
+            assert sum(led.cache._sizes.values()) > 0   # overstates
+            continue
+        if step == "append":
+            for tr, p in zip(ds.traces, paths):
+                append_rank_db(p, trace_remainder(tr, cutoff))
+            continue
+        queries = [qa] if step == "ingest" else step
+        rescans = led.cache.rescans
+        infos = []
+        for svc in (led, ref):
+            if step == "ingest":
+                pend = [svc.submit_ingest(paths, queries)]
+            else:
+                pend = [svc.submit([q]) for q in queries]
+            assert svc.drain_once(block_s=0.0) == len(pend)
+            assert all(p.error is None for p in pend), pend[0].error
+            infos.append(pend[0].tick_info)
+        if step == "ingest":
+            assert infos[0]["ingest"]["rows_ingested"] > 0
+            assert led.cache.rescans == rescans + 1
+            assert planted not in led.cache._sizes
+        on_disk = _disk_sizes(led.store)
+        if i == 0:
+            # the planted summary sits at the cold end: first to go
+            assert (next(iter(led.cache._order)) == planted
+                    if planted in on_disk else infos[0]["evicted"] >= 1)
+        assert infos[0]["evicted"] == infos[1]["evicted"], i
+        assert on_disk == _disk_sizes(ref.store), i
+        assert [k for k in led.cache._order if k in on_disk] \
+            == list(ref.cache._order), i
+        own = {ln.summary_key for ln in QueryPlan.compile(
+            led.store, queries).lanes}
+        assert own <= set(on_disk), i                  # same-tick immunity
+        assert sum(on_disk.values()) <= budget or set(on_disk) <= own, i
+        assert sum(led.cache._sizes.values()) >= sum(on_disk.values()), i
+    assert led.cache._sizes == _disk_sizes(led.store)
+    assert led.cache.evictions == ref.cache.evictions
+    if budget == 10**9:
+        assert led.cache.evictions == 0
+        assert led.cache.rescans == 1                   # the ingest tick
+    else:
+        assert led.cache.evictions > 0

@@ -5,6 +5,7 @@ a store build that stays free of JAX."""
 import glob
 import json
 import os
+import shutil
 import subprocess
 import sys
 import threading
@@ -13,8 +14,9 @@ import urllib.request
 
 import pytest
 
-from repro.core import (append_rank_db, run_append, run_generation,
-                        trace_remainder, truncate_trace, write_rank_db)
+from repro.core import (Query, append_rank_db, run_append,
+                        run_generation, trace_remainder, truncate_trace,
+                        write_rank_db)
 from repro.core.events import SyntheticSpec, generate_synthetic
 from repro.core.spans import TOTALS, span
 from repro.serve.query_service import QueryService, ServiceConfig
@@ -184,6 +186,41 @@ def test_stats_route_carries_the_span_totals(store_dir, tmp_path):
     assert spans["repro.tick.exec"]["stats"]["requests"] >= 1
     assert "tick" not in spans["repro.tick.exec"]["stats"]
     assert spans["repro.shard.read"]["stats"]["rows"] > 0
+
+
+def test_summary_hit_ticks_make_no_syscall_in_eviction(store_dir,
+                                                       tmp_path):
+    """Under the default summary budget, the eviction step of a tick
+    that only hits the summary cache neither lists nor stats the store:
+    ``repro.commit.evict`` counts one stat call, for the one summary
+    the first (cold) ask wrote, and no listing; ``/v1/stats`` counts no
+    summary rescan. The ledger then holds the store's summary bytes."""
+    store = shutil.copytree(store_dir, str(tmp_path / "store"))
+    svc = QueryService(store, ServiceConfig(tick_ms=1.0, port=0))
+    svc.start(serve_http=True)
+    before = TOTALS.snapshot().get("repro.commit.evict",
+                                   {"count": 0, "stats": {}})
+    try:
+        for _ in range(4):
+            p = svc.submit([Query(metrics=("m_bytes",),
+                                  group_by="src_rank")])
+            assert p.done.wait(120) and p.error is None
+        assert p.results[0]["cache_hit"]
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{svc.cfg.port}/v1/stats",
+                timeout=60) as r:
+            stats = json.loads(r.read())
+    finally:
+        svc.stop()
+    got = stats["spans"]["repro.commit.evict"]
+    assert got["count"] - before["count"] == 4
+    assert got["stats"]["rescan"] - before["stats"].get("rescan", 0) == 0
+    assert (got["stats"]["stat_calls"]
+            - before["stats"].get("stat_calls", 0)) == 1
+    assert stats["summary_rescans"] == 0 and stats["evictions"] == 0
+    on_disk = {k: os.path.getsize(os.path.join(store, f"summary_{k}.npz"))
+               for k in svc.store.summary_keys()}
+    assert svc.cache._sizes == on_disk
 
 
 def test_ingest_tick_spans_its_append_phases(tmp_path):
